@@ -27,7 +27,7 @@ shard_map program executes the ENTIRE run as a ``lax.while_loop``:
        under ``packed``), with the support vector all-gathered so every
        device can fill the run outputs;
     4. reduce — verdict-masked prefix-sum compaction of survivors into
-       the SPP parent slots, cond-gated ``materialize_one`` per slot;
+       the SPP parent slots, ``materialize_prefix`` over the survivors;
     5. bookkeeping — per-level stats row (candidates, survivors,
        overflow, imbalance, bail flags), survivor supports and codes
        written at the level's slot of the run outputs.
@@ -71,13 +71,14 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from ..kernels.ops import (Backend, device_local_supports,
                            fused_level_supports,
                            fused_level_supports_packed, is_fused_backend)
 from ..runtime import jax_compat
 from .candgen import device_candidates, device_schedule
-from .embedding import LevelOL, materialize_one
+from .embedding import materialize_prefix
 from .level_step import _IMBAL_FX, wire_checksum
 from .mapreduce import MiningMesh, reduce_supports, worker_imbalance
 
@@ -163,10 +164,19 @@ def _run_program(mmesh: MiningMesh, minsup: int, backend: Backend,
     CB = c_budget
     NL = n_levels
 
+    def graph_minor(pol, pmask):
+        # pin the carried store graph-minor (the layout the compiled
+        # kernel reads); left free, XLA lays the loop carry out with K
+        # minor-most, padding it 16x and copying it every iteration
+        if not fused or interpret:
+            return pol, pmask
+        return (with_layout_constraint(pol, Layout((0, 1, 3, 4, 2))),
+                with_layout_constraint(pmask, Layout((0, 1, 3, 2))))
+
     def core(k_stop, k0, n_par0, codes0, triples, pol, pmask, src, dst,
              emask, out_codes0, out_sups0, out_stats0, ok0, tovf0):
         SPP = codes0.shape[0]
-        PP, _, G, M, K = pol.shape
+        M, K = pol.shape[3:]
 
         def body(carry):
             (k, n_par, codes, pol, pmask,
@@ -184,7 +194,7 @@ def _run_program(mmesh: MiningMesh, minsup: int, backend: Backend,
                     meta, n_cand, tile_c=tile_c, n_triples=n_triples,
                     rows=sched_rows)
                 if packed:
-                    sup_pp, emb_s, _vbits = fused_level_supports_packed(
+                    sup_pp, emb_s = fused_level_supports_packed(
                         sched, tiles, pol, pmask, src, dst, emask,
                         interpret=interpret)
                 else:
@@ -219,28 +229,11 @@ def _run_program(mmesh: MiningMesh, minsup: int, backend: Backend,
             valid_s = jnp.arange(SPP) < n_keep
             cmeta = jnp.take(meta, surv, axis=0)             # (SPP, 5)
 
-            def per_slot(slot):
-                cand, valid = slot
-
-                def do(_):
-                    ch, mk, over = jax.vmap(
-                        lambda po, pm, s, d, e: materialize_one(
-                            LevelOL(po, pm), s, d, e, cand,
-                            max_embeddings=M, out_width=K)
-                    )(pol, pmask, src, dst, emask)
-                    return ch, mk, over.sum()
-
-                def skip(_):
-                    return (jnp.full((PP, G, M, K), -1, jnp.int32),
-                            jnp.zeros((PP, G, M), bool),
-                            jnp.zeros((), jnp.int32))
-
-                return jax.lax.cond(valid, do, skip, None)
-
-            ol_s, mask_s, over_s = jax.lax.map(per_slot, (cmeta, valid_s))
-            new_pol = jnp.moveaxis(ol_s, 0, 1)       # (PP, SPP, G, M, K)
-            new_pmask = jnp.moveaxis(mask_s, 0, 1)
-            overflow = jax.lax.psum(over_s.sum(), axes)
+            new_pol, new_pmask, over = materialize_prefix(
+                cmeta, jnp.minimum(n_keep, SPP), pol, pmask, src, dst,
+                emask, n_slots=SPP, max_embeddings=M, out_width=K)
+            new_pol, new_pmask = graph_minor(new_pol, new_pmask)
+            overflow = jax.lax.psum(over, axes)
 
             # 5. run-output bookkeeping at this level's slot
             cost_pp = (emb_pp * real[None, :].astype(emb_pp.dtype)).sum(1)
@@ -270,6 +263,7 @@ def _run_program(mmesh: MiningMesh, minsup: int, backend: Backend,
             ok = carry[8]
             return (k < k_stop) & (n_par > 0) & ok
 
+        pol, pmask = graph_minor(pol, pmask)
         carry = (k0, n_par0, codes0, pol, pmask,
                  out_codes0, out_sups0, out_stats0, ok0, tovf0)
         if unroll > 0:

@@ -22,11 +22,12 @@ materializes and *ships* OLs for every locally-non-zero candidate; we
 materialize survivors only, locally):
 
   pass 1  local_supports()   -> (C,) per-graph-any popcount   [hot path]
-  pass 2  materialize_ol()   -> compacted child OLs for frequent c only
+  pass 2  materialize_prefix() -> compacted child OLs for frequent c only
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Sequence
 
 import jax
@@ -42,7 +43,7 @@ __all__ = [
     "EdgeOL", "LevelOL", "CandidateMeta",
     "build_edge_ol", "level1_ol", "candidate_meta",
     "join_valid", "local_supports_ref", "support_bits_ref",
-    "materialize_one", "materialize_ol",
+    "materialize_rows", "materialize_prefix",
 ]
 
 PAD = -1
@@ -244,39 +245,33 @@ def support_bits_ref(
     return sup, emb, vbits
 
 
-def materialize_one(
-    level: LevelOL,
-    eol_src: jnp.ndarray, eol_dst: jnp.ndarray, eol_mask: jnp.ndarray,
+def materialize_rows(
+    pol: jnp.ndarray,           # (G, M, K) the candidate's parent OL
+    pmask: jnp.ndarray,         # (G, M)
+    src: jnp.ndarray,           # (G, F) the candidate's triple edge-OL
+    dst: jnp.ndarray,
+    em: jnp.ndarray,
     cand: jnp.ndarray,          # (5,) one candidate row
     *,
     max_embeddings: int,
     out_width: int | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Child OL of ONE candidate: (G, Mc, W) rows, (G, Mc) mask, and
-    the scalar overflow (matches dropped by the Mc cap).  The single-slot
-    building block: `materialize_ol` maps it over a survivor batch, and
-    the level program (`core/level_step.py`) cond-gates it per compact
-    slot so cap padding costs nothing.
+    """Child OL of one candidate from its parent's and triple's rows —
+    the single-slot building block of every materialization.
 
     ``out_width`` is the child's vertex-slot width W (default K+1, the
     exact unbucketed growth).  Under shape bucketing the parent store is
     already wider than its real pattern, so W may equal K — the new
     vertex then lands in a slot that held PAD — and must never shrink
     below it."""
-    G, M, K = level.ol.shape[1:]
-    F = eol_src.shape[-1]
+    G, M, K = pol.shape
+    F = src.shape[-1]
     Mc = max_embeddings
     W = K + 1 if out_width is None else out_width
     if W < K:
         raise ValueError(f"out_width={W} below parent vertex width {K}")
 
-    parent, stub, to, fwd, tidx = (cand[0], cand[1], cand[2], cand[3],
-                                   cand[4])
-    pol = jnp.take(level.ol, parent, axis=0)
-    pmask = jnp.take(level.mask, parent, axis=0)
-    src = jnp.take(eol_src, tidx, axis=0)
-    dst = jnp.take(eol_dst, tidx, axis=0)
-    em = jnp.take(eol_mask, tidx, axis=0)
+    stub, to, fwd = cand[1], cand[2], cand[3]
     valid = join_valid(pol, pmask, src, dst, em, stub, to, fwd)  # (G,M,F)
 
     # child embedding (m, f): parent row m extended by dst[f] (forward)
@@ -325,23 +320,36 @@ def materialize_one(
     return child.astype(jnp.int32), picked, overflow
 
 
-def materialize_ol(
-    level: LevelOL,
-    eol_src: jnp.ndarray, eol_dst: jnp.ndarray, eol_mask: jnp.ndarray,
-    meta: jnp.ndarray,          # (C', 5) — surviving candidates only
-    *,
-    max_embeddings: int,
-    out_width: int | None = None,
-) -> tuple[LevelOL, jnp.ndarray]:
-    """Compacted child OLs for the surviving candidates (pass 2).
+def materialize_prefix(cmeta, n_fill, pol, pmask, src, dst, emask, *,
+                       n_slots: int, max_embeddings: int, out_width: int):
+    """Child OL store of the first ``n_fill`` compact survivor slots.
 
-    Returns the next LevelOL (``out_width`` vertex slots, default K+1)
-    and the per-candidate overflow count (matches dropped by the M cap
-    — exactness telemetry).
-    """
-    child, mask, over = jax.lax.map(
-        lambda cand: materialize_one(level, eol_src, eol_dst, eol_mask,
-                                     cand, max_embeddings=max_embeddings,
-                                     out_width=out_width),
-        meta)
-    return LevelOL(child, mask), over
+    ``cmeta`` (n_slots, 5) holds the survivors' candidate rows; slots at
+    and past ``n_fill`` (a traced count) keep the PAD / all-False fill.
+    Each slot slices its parent's and triple's rows out of the device-
+    local stores (PP, P, G, M, K) / (PP, T, G, F) and writes its child
+    rows in place into the (PP, n_slots, G, Mc, W) output — no stacked
+    per-slot buffer, no transposed copy of either store.  Returns
+    ``(ol, mask, overflow)`` with the device-local overflow count."""
+    PP, _, G, _, _ = pol.shape
+
+    def one(i, carry):
+        ol, mask, over = carry
+        cand = cmeta[i]
+        take = functools.partial(jax.lax.dynamic_index_in_dim, axis=1,
+                                 keepdims=False)
+        ch, mk, ov = jax.vmap(functools.partial(
+            materialize_rows, cand=cand, max_embeddings=max_embeddings,
+            out_width=out_width))(
+                take(pol, cand[0]), take(pmask, cand[0]),
+                take(src, cand[4]), take(dst, cand[4]),
+                take(emask, cand[4]))
+        ol = jax.lax.dynamic_update_index_in_dim(ol, ch, i, axis=1)
+        mask = jax.lax.dynamic_update_index_in_dim(mask, mk, i, axis=1)
+        return ol, mask, over + ov.sum()
+
+    init = (jnp.full((PP, n_slots, G, max_embeddings, out_width), -1,
+                     jnp.int32),
+            jnp.zeros((PP, n_slots, G, max_embeddings), bool),
+            jnp.zeros((), jnp.int32))
+    return jax.lax.fori_loop(0, n_fill, one, init)

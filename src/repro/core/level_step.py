@@ -120,7 +120,7 @@ from ..kernels.ops import (Backend, device_local_supports,
                            fused_level_supports,
                            fused_level_supports_packed, is_fused_backend)
 from ..runtime import faults, jax_compat
-from .embedding import LevelOL, materialize_one
+from .embedding import materialize_prefix
 from .mapreduce import MiningMesh, reduce_supports, worker_imbalance
 
 __all__ = ["LevelWire", "LevelOutputs", "PendingLevel", "dispatch_level",
@@ -397,7 +397,7 @@ def _level_program(mmesh: MiningMesh, minsup: int,
             if packed:
                 # verdict accumulator = ceil(G/32) uint32 words in VMEM;
                 # local support counting is AND+popcount per tile_c block
-                sup_pp, emb_s, _vbits = fused_level_supports_packed(
+                sup_pp, emb_s = fused_level_supports_packed(
                     sched_meta, tiles, pol, pmask, src, dst, emask,
                     interpret=interpret)
             else:
@@ -470,35 +470,13 @@ def _level_program(mmesh: MiningMesh, minsup: int,
                  | jnp.where(n_keep > c_real, AUDIT_NKEEP, 0)
                  ).astype(jnp.int32)
 
-        # pass 2, cond-gated per compact slot: lax.map is a scan, so the
-        # skip branch of invalid (cap-padding) slots really executes a
-        # constant fill — unlike a vmapped select, padding costs ~nothing
-        PP, _, G, _, K = pol.shape
-        Mc = max_embeddings
-        Wk = child_width if child_width is not None else K + 1
-
-        def per_slot(slot):
-            cand, valid = slot
-
-            def do(_):
-                ch, mk, over = jax.vmap(
-                    lambda po, pm, s, d, e: materialize_one(
-                        LevelOL(po, pm), s, d, e, cand,
-                        max_embeddings=Mc, out_width=Wk)
-                )(pol, pmask, src, dst, emask)
-                return ch, mk, over.sum()
-
-            def skip(_):
-                return (jnp.full((PP, G, Mc, Wk), -1, jnp.int32),
-                        jnp.zeros((PP, G, Mc), bool),
-                        jnp.zeros((), jnp.int32))
-
-            return jax.lax.cond(valid, do, skip, None)
-
-        ol_s, mask_s, over_s = jax.lax.map(per_slot, (cmeta, valid_s))
-        ol = jnp.moveaxis(ol_s, 0, 1)           # (PP, S, G, Mc, Wk)
-        mask = jnp.moveaxis(mask_s, 0, 1)       # (PP, S, G, Mc)
-        overflow = jax.lax.psum(over_s.sum(), axes)
+        # pass 2 over the valid compact slots only: cap padding keeps
+        # the constant fill and costs nothing
+        Wk = child_width if child_width is not None else pol.shape[-1] + 1
+        ol, mask, over = materialize_prefix(
+            cmeta, jnp.minimum(n_keep, S), pol, pmask, src, dst, emask,
+            n_slots=S, max_embeddings=max_embeddings, out_width=Wk)
+        overflow = jax.lax.psum(over, axes)
         cost_pp = (emb_pp * real[None, :].astype(emb_pp.dtype)).sum(1)
         if not sharded:
             return gsup, n_keep, overflow, audit, ol, mask, cost_pp

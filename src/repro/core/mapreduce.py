@@ -42,7 +42,7 @@ from ..kernels.ops import (Backend, default_backend, device_local_supports,
                            is_fused_backend, is_packed_backend)
 from ..runtime import jax_compat
 from .candgen import schedule_candidates
-from .embedding import materialize_ol, LevelOL
+from .embedding import materialize_prefix
 
 __all__ = ["MiningMesh", "map_reduce_supports", "map_materialize",
            "reduce_supports", "worker_imbalance"]
@@ -174,7 +174,7 @@ def _support_program_fused(mmesh: MiningMesh, minsup: int,
 
     def program(sched_meta, tiles, inv, pol, pmask, src, dst, emask):
         if packed:
-            sup_pp, emb_pp_s, _vbits = fused_level_supports_packed(
+            sup_pp, emb_pp_s = fused_level_supports_packed(
                 sched_meta, tiles, pol, pmask, src, dst, emask,
                 interpret=interpret)                # (PP, Cs) scheduled
         else:
@@ -249,18 +249,17 @@ def _materialize_program(mmesh: MiningMesh, max_embeddings: int,
     rep = mmesh.replicated()
 
     def program(meta, pol, pmask, src, dst, emask):
-        def per_part(po, pm, s, d, e):
-            lvl, over = materialize_ol(
-                LevelOL(po, pm), s, d, e, meta,
-                max_embeddings=max_embeddings, out_width=out_width)
-            return lvl.ol, lvl.mask, over.sum()
-        ol, mask, over = jax.vmap(per_part)(pol, pmask, src, dst, emask)
-        return ol, mask, jax.lax.psum(over.sum(), axes)
+        n = meta.shape[0]
+        width = out_width if out_width is not None else pol.shape[-1] + 1
+        ol, mask, over = materialize_prefix(
+            meta, n, pol, pmask, src, dst, emask, n_slots=n,
+            max_embeddings=max_embeddings, out_width=width)
+        return ol, mask, jax.lax.psum(over, axes)
 
     return jax.jit(jax_compat.shard_map(
         program, mesh=mmesh.mesh,
         in_specs=(rep, parts, parts, parts, parts, parts),
-        out_specs=(parts, parts, rep)))
+        out_specs=(parts, parts, rep), check_vma=False))
 
 
 def map_materialize(

@@ -52,6 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels.fused_level import LANES
 from ..kernels.ops import Backend, default_backend, is_fused_backend
 from ..runtime import checkpoint as ckpt
 from ..runtime import faults
@@ -76,6 +77,11 @@ __all__ = ["MirageConfig", "LevelStats", "DistMiningResult",
 
 PIPELINES = ("single_sync", "device_loop", "legacy")
 CANDGENS = ("host", "device")
+
+# child stores a level may hold at once, in units of the cap's store:
+# the program's own (S slots at M) plus a retry's beside it (S slots at
+# up to 2·M) — see Mirage._fit_cap
+_CAP_STORES = 3
 
 
 class DonationRetryRebuild(RuntimeError):
@@ -392,6 +398,7 @@ class Mirage:
         self.auditor: Optional[Auditor] = None
         self._watchdog: Optional[Watchdog] = None
         self._ckpt_meta: dict = {}
+        self._graphs_axis: Optional[int] = None   # store G, set by fit()
         if config.n_partitions % self.mesh.n_workers:
             raise ValueError(
                 f"n_partitions={config.n_partitions} must be a multiple of "
@@ -471,6 +478,13 @@ class Mirage:
 
         # ---- phase 2: preparation (host, once) -------------------------
         G = max((len(p) for p in part.partitions), default=1)
+        backend = cfg.backend or default_backend()
+        if is_fused_backend(backend) and not backend.endswith("interpret"):
+            # a lane-aligned graph axis keeps G minor-most in XLA's TPU
+            # layout of every store, so the kernel's graph-minor views
+            # stay bitcasts (padded graphs carry all-False masks)
+            G = round_up_multiple(G, LANES)
+        self._graphs_axis = G
         eols = [build_edge_ol(p, triples, pad_graphs=G, max_occ=cfg.max_occ)
                 for p in part.partitions]
         F = max(e.src.shape[-1] for e in eols)
@@ -721,12 +735,15 @@ class Mirage:
     def _repad_saved(self, pol, pmask):
         """Re-bucket a checkpoint's canonical (padding-stripped) survivor
         store into the CURRENT config's shape family — shared by resume
-        and mid-run parent rebuild.  No-op without bucketing."""
+        and mid-run parent rebuild — and pad its graph axis to this
+        run's (a writer on another backend may have aligned it
+        differently)."""
         bk = self._buckets()
+        g_to = self._graphs_axis
         if bk is None:
-            return pol, pmask
+            return _pad_store(pol, pmask, g_to=g_to)
         return _pad_store(
-            pol, pmask,
+            pol, pmask, g_to=g_to,
             p_to=bucket_size(pol.shape[1], bk.s_floor),
             m_to=bk.embeddings(pol.shape[3], self.cfg.max_embeddings),
             k_to=bk.vertex_slots(pol.shape[-1]))
@@ -744,6 +761,14 @@ class Mirage:
         sharding = partition_sharding(self.mesh.mesh)
         return (jax.device_put(jnp.asarray(pol), sharding),
                 jax.device_put(jnp.asarray(pmask), sharding))
+
+    # ------------------------------------------------------------------
+    def kernel_path(self, n_graphs: int) -> tuple[str, bool]:
+        """``(backend, packed)``: the support kernel a fit over
+        ``n_graphs`` graphs runs — the resolved backend name and whether
+        it takes the bit-packed path."""
+        return (self.cfg.backend or default_backend(),
+                self._packed_support(n_graphs))
 
     # ------------------------------------------------------------------
     def _sharded_wire(self) -> bool:
@@ -830,6 +855,35 @@ class Mirage:
         if bk is not None:
             s = bk.survivors(s, Cp)
         return s
+
+    # ------------------------------------------------------------------
+    def _fit_cap(self, S: int, pol, M: int,
+                 child_width: Optional[int]) -> int:
+        """Clamp the survivor cap so the child stores it sizes fit the
+        memory the mesh's devices have free.
+
+        The cap never decides correctness — a miss costs one
+        materialize-only retry of the true survivors — but every cap
+        slot holds a full (G, M, W) child OL per local partition, and
+        an over-predicted cap (a wide candidate level with sparse
+        survival) could ask for more HBM than a chip has.  The parents
+        and edge OLs are already counted in ``bytes_in_use``; beside
+        them the level holds its own child store and, on an escalation
+        retry, a second one at up to twice M — ``_CAP_STORES`` stores of
+        the cap's size in all.  Backends that report no memory limit
+        (the CPU) keep S.  Under bucketing the clamp rounds down into
+        the S family; a fit below the family floor is kept as is."""
+        free = _free_device_bytes(self.mesh.mesh.devices.flat)
+        if free is None:
+            return S
+        NP, _, G, _, K = pol.shape
+        width = child_width if child_width is not None else K + 1
+        slot = (NP // self.mesh.n_workers) * G * M * (4 * width + 1)
+        fit = max(1, free // (_CAP_STORES * slot))
+        bk = self._buckets()
+        if bk is not None and fit >= bk.s_floor:
+            fit = bk.s_floor << ((fit // bk.s_floor).bit_length() - 1)
+        return min(S, fit)
 
     # ------------------------------------------------------------------
     def _device_candgen(self, parents: list[Code],
@@ -1001,11 +1055,15 @@ class Mirage:
                 k_cur = k_stop
                 if wd is not None:
                     wd.disarm(observe_s=time.perf_counter() - t_chunk)
-                if not rw.ok or rw.n_par == 0:
+                if not rw.ok:
                     break
+                # overflow first: a run that reached its fixpoint
+                # (n_par == 0) may still have capped embeddings on the way
                 if (rw.total_overflow > 0
                         and M_run < cfg.max_embeddings_limit):
                     escalate = True
+                    break
+                if rw.n_par == 0:
                     break
                 if cfg.checkpoint_dir and k_cur < L:
                     levels, sups, _ = self._decode_device_run(
@@ -1108,6 +1166,7 @@ class Mirage:
         Cp = meta_p.shape[0]
         backend = cfg.backend or default_backend()
         S = self._survivor_cap(C, Cp, history)
+        S = self._fit_cap(S, pol, M, child_width)
         # chaos hook: a cap-miss storm forces a pathological cap, driving
         # every hit level through the materialize-only retry path
         S = faults.override_cap(S, level)
@@ -1308,13 +1367,27 @@ class Mirage:
                                  **self._ckpt_meta})
 
 
+def _free_device_bytes(devices) -> Optional[int]:
+    """Least free memory (``bytes_limit - bytes_in_use``) over
+    ``devices``, or None where a backend reports no limit (the CPU)."""
+    free = []
+    for dev in devices:
+        stats = dev.memory_stats() or {}
+        if not stats.get("bytes_limit"):
+            return None
+        free.append(stats["bytes_limit"] - stats.get("bytes_in_use", 0))
+    return min(free)
+
+
 def _pad_store(pol, pmask, *, p_to: Optional[int] = None,
-               m_to: Optional[int] = None, k_to: Optional[int] = None):
+               m_to: Optional[int] = None, k_to: Optional[int] = None,
+               g_to: Optional[int] = None):
     """Grow an OL store (NP, P, G, M, K)/(NP, P, G, M) into its bucket:
     PAD(-1) vertex entries, all-False masks.  Padded slots are inert —
     no candidate references a padded parent, masked embeddings never
-    join, PAD vertex slots never match.  Works on numpy or device
-    arrays (np.pad falls back to jnp dispatch via asarray semantics)."""
+    join, PAD vertex slots never match, padded graphs hold nothing.
+    Works on numpy or device arrays (np.pad falls back to jnp dispatch
+    via asarray semantics)."""
     xp = np if isinstance(pol, np.ndarray) else jnp
 
     def pad(a, axis, to):
@@ -1326,8 +1399,8 @@ def _pad_store(pol, pmask, *, p_to: Optional[int] = None,
         fill = -1 if a.dtype == xp.int32 else False
         return xp.pad(a, widths, constant_values=fill)
 
-    pol = pad(pad(pad(pol, 1, p_to), 3, m_to), 4, k_to)
-    pmask = pad(pad(pmask, 1, p_to), 3, m_to)
+    pol = pad(pad(pad(pad(pol, 1, p_to), 2, g_to), 3, m_to), 4, k_to)
+    pmask = pad(pad(pad(pmask, 1, p_to), 2, g_to), 3, m_to)
     return pol, pmask
 
 

@@ -29,10 +29,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .bitset import WORD, n_words, tail_mask
+from .bitset import n_words, tail_mask
 from .embedding_join import DEFAULT_TILE_G, embedding_join_pallas
 from .fused_level import (DEFAULT_TILE_C, fused_level_packed_pallas,
-                          fused_level_pallas)
+                          fused_level_pallas, graph_tile)
 from .ref import embedding_join_ref, support_count_ref
 from .support_count import support_count_pallas
 
@@ -84,18 +84,11 @@ def fused_level_supports(
     kernel launch covering every device-local partition.
 
     Outputs are in scheduled order — gather with ``schedule.inv`` for
-    canonical order.  Owns graph-axis padding (padded graphs carry zero
-    masks, contributing nothing).
+    canonical order.  The graph axis needs no padding: the kernel masks
+    the lanes of an overhanging last graph tile.
     """
-    G = pol.shape[2]
-    tg = min(tile_g, _round_up(G, 8))
-    polp = _pad_to(pol, 2, tg, value=-1)
-    pmaskp = _pad_to(pmask.astype(jnp.int8), 2, tg)
-    srcp = _pad_to(src, 2, tg, value=-1)
-    dstp = _pad_to(dst, 2, tg, value=-1)
-    emaskp = _pad_to(emask.astype(jnp.int8), 2, tg)
-    return fused_level_pallas(sched_meta, tiles, polp, pmaskp, srcp, dstp,
-                              emaskp, tile_g=tg, interpret=interpret)
+    return fused_level_pallas(sched_meta, tiles, pol, pmask, src, dst,
+                              emask, tile_g=tile_g, interpret=interpret)
 
 
 def fused_level_supports_packed(
@@ -109,29 +102,21 @@ def fused_level_supports_packed(
     *,
     tile_g: int = DEFAULT_TILE_G,
     interpret: bool = False,
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Packed twin of :func:`fused_level_supports` (DESIGN.md §12).
 
-    Owns the 32-aligned graph-axis padding and builds the valid-graph
-    bit mask: tile_g rounds to a multiple of 32 so graph tiles pack to
-    whole uint32 words, and ``gmask`` zeroes the ragged padded-G tail
-    (padded graphs also carry zero masks — the lane-AND is the second
-    line of defence that makes the bitset contract local).  Returns
-    ``(sup, emb, vbits)`` in scheduled order; ``vbits`` is the
-    per-candidate per-graph verdict bitset, ``(PP, Cs, ceil(G/32))``
-    uint32 with the pad-bit tail zero.
+    Builds the valid-graph bit mask: each graph tile packs to whole
+    uint32 words, and ``gmask`` zeroes the bits past G (padded graphs
+    and overhanging lanes also carry no match — the lane-AND is the
+    second line of defence that makes the bitset contract local).
+    Returns ``(sup, emb)`` in scheduled order, as the dense kernel.
     """
     G = pol.shape[2]
-    tg = min(_round_up(tile_g, WORD), _round_up(G, WORD))
-    polp = _pad_to(pol, 2, tg, value=-1)
-    pmaskp = _pad_to(pmask.astype(jnp.int8), 2, tg)
-    srcp = _pad_to(src, 2, tg, value=-1)
-    dstp = _pad_to(dst, 2, tg, value=-1)
-    emaskp = _pad_to(emask.astype(jnp.int8), 2, tg)
-    Gp = polp.shape[2]
-    gmask = jnp.asarray(tail_mask(G, words=n_words(Gp)))
-    return fused_level_packed_pallas(sched_meta, tiles, gmask, polp, pmaskp,
-                                     srcp, dstp, emaskp, tile_g=tg,
+    tg = graph_tile(tile_g, G)
+    words = -(-G // tg) * n_words(tg)
+    gmask = jnp.asarray(tail_mask(G, words=words))
+    return fused_level_packed_pallas(sched_meta, tiles, gmask, pol, pmask,
+                                     src, dst, emask, tile_g=tile_g,
                                      interpret=interpret)
 
 
@@ -208,7 +193,7 @@ def level_supports(
         sched = schedule_candidates(np.asarray(meta), tile_c)
         interpret = backend.endswith("interpret")
         if is_packed_backend(backend):
-            sup, emb, _ = fused_level_supports_packed(
+            sup, emb = fused_level_supports_packed(
                 jnp.asarray(sched.meta), jnp.asarray(sched.tiles),
                 pol[None], pmask[None], src[None], dst[None], emask[None],
                 tile_g=tile_g, interpret=interpret)

@@ -117,12 +117,17 @@ def main() -> None:
                     help="write the auditor's per-level report JSON here")
     args = ap.parse_args()
 
+    import jax
+
     from repro.core.graphdb import (GraphValidationError, paper_toy_db,
                                     pubchem_like_db, random_db)
     from repro.core.mining import Mirage, MirageConfig, PartialResult
     from repro.core.supervisor import MiningSupervisor, SupervisorConfig
     from repro.runtime import faults
+    from repro.runtime.compile_cache import enable_compile_cache
     from repro.runtime.watchdog import Watchdog
+
+    enable_compile_cache()
 
     if args.dataset == "paper-toy":
         graphs = paper_toy_db()
@@ -211,6 +216,11 @@ def main() -> None:
     if partial:
         print(f"[mine] PARTIAL RESULT ({res.reason}): verified prefix "
               f"through level {res.last_level}, audited={res.audited}")
+    dev = jax.devices()[0]
+    ran = miner if sup is None else sup.last_miner   # after any ladder rung
+    backend, packed = ran.kernel_path(len(graphs))
+    print(f"[mine] device: {dev.platform} {dev.device_kind} "
+          f"x{len(jax.devices())}  backend={backend} packed={packed}")
     print(f"[mine] |G|={len(graphs)} minsup={res.minsup} "
           f"partitions={args.partitions} scheme={args.scheme} "
           f"reduce={cfg.reduce}")

@@ -1,57 +1,31 @@
-"""Version-compatibility shims for JAX API drift.
+"""Mesh and shard_map construction in one place.
 
-The codebase targets the current JAX API surface (``jax.shard_map``,
-``jax.sharding.AxisType``, ``jax.make_mesh(axis_types=...)``), but must
-also run on older installs where ``shard_map`` still lives in
-``jax.experimental`` (with ``check_rep`` instead of ``check_vma``) and
-meshes carry no axis types.  Every mesh/shard_map construction in the
-repo goes through this module so the drift is handled in exactly one
-place.
+Every mesh and shard_map in the repo is built here, with the call
+shapes the rest of the code uses: meshes whose axes are all explicit
+``Auto`` axes, and ``shard_map`` with the varying-axis checker as a
+keyword.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Sequence
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 __all__ = ["make_mesh", "shard_map"]
 
 
-def _make_mesh_takes_axis_types() -> bool:
-    try:
-        return "axis_types" in inspect.signature(jax.make_mesh).parameters
-    except (TypeError, ValueError):   # signature unavailable — assume new API
-        return True
-
-
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               devices=None) -> Mesh:
-    """``jax.make_mesh`` with explicit-Auto axis types where supported.
-
-    Newer JAX requires (or defaults differently) ``axis_types``; older
-    JAX rejects the kwarg entirely.  Semantics are identical for our
-    usage — every axis is a plain Auto/manual-collective axis.
-    """
-    kwargs = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None and _make_mesh_takes_axis_types():
-        kwargs["axis_types"] = (axis_type.Auto,) * len(tuple(axis_names))
-    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+    """``jax.make_mesh`` with every axis an ``Auto`` axis (a plain
+    manual-collective axis under shard_map)."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """``jax.shard_map`` if present, else the experimental spelling.
-
-    ``check_vma`` (new name) and ``check_rep`` (old name) toggle the same
-    replication/varying-axis checker.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+    """``jax.shard_map``; ``check_vma`` toggles the varying-axis
+    checker."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)

@@ -422,3 +422,25 @@ def test_chunk_cadence():
     assert whole.boundaries() == [6]
     assert whole.max_fetches() == 1
     assert ckpt.ChunkCadence(3, 4, 1).boundaries() == [4]
+
+
+def test_escalation_when_run_reaches_fixpoint():
+    """A run that ends at its fixpoint (no survivors left) after capping
+    embeddings on the way must escalate M and rerun, not fall back:
+    4-leaf stars hold 12-24 embeddings of each star pattern, far past
+    the M=4 level-1 cap, and level 5 (a 5-leaf star) has no support."""
+    from repro.core.graphdb import Graph
+
+    graphs = [Graph([1, 0, 0, 0, 0], [(0, i) for i in range(1, 5)],
+                    [0] * 4) for _ in range(4)]
+    ref = mine_host(graphs, 2)
+    miner = Mirage(MirageConfig(
+        minsup=2, n_partitions=2, pipeline="device_loop",
+        max_size=len(ref.levels) + 1, max_embeddings=2,
+        device_max_states=128))
+    res = miner.fit(graphs)
+    info = miner.last_device_loop
+    assert info["completed"] and info["fallback"] is None, info
+    assert info["escalations"] >= 1
+    assert [set(lv) for lv in res.levels] == [set(lv) for lv in ref.levels]
+    assert res.supports == {c: p.support for c, p in ref.frequent.items()}

@@ -6,7 +6,7 @@ import pytest
 from repro.core.candgen import generate_candidates
 from repro.core.embedding import (build_edge_ol, candidate_meta, join_valid,
                                   level1_ol, local_supports_ref,
-                                  materialize_ol, LevelOL)
+                                  materialize_prefix, LevelOL)
 from repro.core.graphdb import paper_toy_db, random_db
 from repro.core.host_miner import frequent_edges, mine_host
 
@@ -41,9 +41,13 @@ def dense_mine_levels(graphs, minsup, max_size, max_embeddings=64, max_occ=None)
         if not keep:
             break
         keep_meta = jnp.asarray(candidate_meta([cands[i] for i in keep], eol))
-        level, over = materialize_ol(level, src, dst, em, keep_meta,
-                                     max_embeddings=max_embeddings)
-        total_overflow += int(np.asarray(over).sum())
+        # the production materializer, on one partition (PP=1)
+        ol, mask, over = materialize_prefix(
+            keep_meta, len(keep), level.ol[None], level.mask[None],
+            src[None], dst[None], em[None], n_slots=len(keep),
+            max_embeddings=max_embeddings, out_width=level.ol.shape[-1] + 1)
+        level = LevelOL(ol[0], mask[0])
+        total_overflow += int(over)
         levels.append([cands[i].code for i in keep])
         for i in keep:
             supports[cands[i].code] = int(sup[i])

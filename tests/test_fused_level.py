@@ -113,6 +113,8 @@ def test_schedule_empty():
     (dict(C=9, P=4, G=24, M=5, K=3, T=5, F=7), 8, 16),
     # single candidate, single graph tile
     (dict(C=1, P=2, G=8, M=4, K=2, T=2, F=4), 8, 8),
+    # several 128-lane graph tiles, the last overhanging G
+    (dict(C=6, P=2, G=300, M=4, K=3, T=2, F=4), 4, 128),
 ])
 def test_fused_matches_ref_and_two_launch(shape, tc, tg):
     rng = np.random.default_rng(100 + shape["G"])

@@ -178,6 +178,51 @@ def test_survivor_cap_rounds_to_bucket_family():
             == m._survivor_cap(C, Cp, [(10, 60, 12)]))
 
 
+_SLOT = 3 * 2 * 100 * 8 * (4 * 5 + 1)   # 3 stores x (NP, G, M, 4W+1)
+
+
+@pytest.mark.parametrize("free,want", [
+    (None, 64),              # no memory limit reported (the CPU): S kept
+    (10 ** 12, 64),          # ample memory: S kept
+    (_SLOT * 20, 16),        # clamp rounds DOWN into the S family
+    (_SLOT * 8, 8),          # exactly the family floor
+    (_SLOT * 5, 5),          # fit below the floor is kept, not raised
+    (_SLOT // 2, 1),         # not even one slot free: one slot
+])
+def test_fit_cap_clamps_to_free_device_memory(monkeypatch, free, want):
+    """The survivor cap's child stores (its own plus a retry's at up to
+    twice M) must fit the least free memory over the mesh's devices,
+    and rounding into the bucket family never raises it past that."""
+    from repro.core import mining
+
+    monkeypatch.setattr(mining, "_free_device_bytes", lambda devices: free)
+    m = Mirage(MirageConfig(minsup=2, n_partitions=2, bucket_shapes=True,
+                            bucket_s_floor=8))
+    pol = np.zeros((2, 4, 100, 8, 4), np.int32)   # (NP, P, G, M, K)
+    got = m._fit_cap(64, pol, 8, None)            # W defaults to K + 1
+    assert got == want
+    if free is not None and free >= _SLOT:
+        assert got * _SLOT <= free
+
+
+def test_free_device_bytes_takes_the_least_free_device():
+    from repro.core.mining import _free_device_bytes
+
+    class Dev:
+        def __init__(self, stats):
+            self.stats = stats
+
+        def memory_stats(self):
+            return self.stats
+
+    a = Dev({"bytes_limit": 100, "bytes_in_use": 30})
+    b = Dev({"bytes_limit": 100, "bytes_in_use": 55})
+    assert _free_device_bytes([a, b]) == 45
+    assert _free_device_bytes([a, Dev(None)]) is None
+    assert _free_device_bytes([Dev({"bytes_in_use": 5})]) is None
+    assert _free_device_bytes(jax.devices()) is None   # the CPU
+
+
 def test_survivor_cap_tightens_from_fanout_without_retries():
     """ISSUE-8 regression: the cap must predict from the previous
     level's per-parent FANOUT, not the survival ratio times the current

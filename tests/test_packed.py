@@ -140,33 +140,50 @@ def test_fused_packed_backend_matches_ref_and_dense(seed):
     np.testing.assert_array_equal(np.asarray(emb_p), np.asarray(emb_d))
 
 
-def test_packed_kernel_vbits_match_oracle_bitsets():
-    """The kernel's per-graph verdict bitset must be bit-identical to
-    the host oracle's (pad tail zero included) — it is the artifact the
-    AND+popcount support count is computed from."""
+@pytest.mark.parametrize("G", [37, 300])
+def test_packed_kernel_vbits_match_oracle_bitsets(G):
+    """The kernel's in-VMEM bit packing (``_pack_words``, one graph tile
+    at a time) must give the host oracle's per-graph verdict bitsets
+    bit for bit (pad tail zero included) — they are what the
+    AND+popcount support count is computed from — and the kernel's
+    supports must be their popcounts.  G=300 spans three 128-lane graph
+    tiles, the last one overhanging the graph axis."""
     from repro.core.candgen import schedule_candidates
     from repro.core.embedding import support_bits_ref
+    from repro.kernels.embedding_join import DEFAULT_TILE_G
+    from repro.kernels.fused_level import _pack_words, graph_tile
     from repro.kernels.ops import fused_level_supports_packed
 
     rng = np.random.default_rng(4)
-    meta, pol, pmask, src, dst, emask = _random_level(rng, C=9, G=37)
+    meta, pol, pmask, src, dst, emask = _random_level(rng, C=9, G=G)
     sup_o, _, vbits_o = support_bits_ref(
         jnp.asarray(meta), jnp.asarray(pol), jnp.asarray(pmask),
         jnp.asarray(src), jnp.asarray(dst), jnp.asarray(emask))
+    gw = bitset.n_words(G)
+    tg = graph_tile(DEFAULT_TILE_G, G)
+    n_g = -(-G // tg)
+    bits = np.zeros((16, n_g * tg), np.int32)   # 8-row candidate blocks
+    bits[:9, :G] = bitset.unpack_bits(np.asarray(vbits_o), G)
+    words = np.concatenate(
+        [np.asarray(_pack_words(jnp.asarray(bits[:, g * tg:(g + 1) * tg]),
+                                bitset.n_words(tg)))
+         for g in range(n_g)], axis=1)
+    np.testing.assert_array_equal(words[:9, :gw], np.asarray(vbits_o))
+    # words past n_words(G) (graph-tile padding) and pad rows are zero
+    np.testing.assert_array_equal(words[:9, gw:], 0)
+    np.testing.assert_array_equal(words[9:], 0)
+
     sched = schedule_candidates(meta)
-    sup_k, _, vbits_k = fused_level_supports_packed(
+    sup_k, _ = fused_level_supports_packed(
         jnp.asarray(sched.meta), jnp.asarray(sched.tiles),
         jnp.asarray(pol)[None], jnp.asarray(pmask)[None],
         jnp.asarray(src)[None], jnp.asarray(dst)[None],
         jnp.asarray(emask)[None], interpret=True)
     inv = np.asarray(sched.inv)
-    gw = bitset.n_words(37)
     np.testing.assert_array_equal(
         np.asarray(sup_k)[0][inv], np.asarray(sup_o))
     np.testing.assert_array_equal(
-        np.asarray(vbits_k)[0][inv][:, :gw], np.asarray(vbits_o))
-    # kernel words past n_words(G) (graph-tile padding) must be zero
-    np.testing.assert_array_equal(np.asarray(vbits_k)[0][:, gw:], 0)
+        np.asarray(sup_o), bitset.popcount(np.asarray(vbits_o)).sum(-1))
 
 
 # ---------------------------------------------------------------------------
