@@ -119,7 +119,7 @@ from jax.sharding import NamedSharding
 from ..kernels.ops import (Backend, device_local_supports,
                            fused_level_supports,
                            fused_level_supports_packed, is_fused_backend)
-from ..runtime import faults, jax_compat
+from ..runtime import faults, jax_compat, tracing
 from .embedding import materialize_prefix
 from .mapreduce import MiningMesh, reduce_supports, worker_imbalance
 
@@ -287,6 +287,9 @@ class LevelOutputs:
     src: jnp.ndarray        # (NP, T, G, F) — edge store (as passed in)
     dst: jnp.ndarray
     emask: jnp.ndarray
+    # host seconds from the dispatch's first host work to the decoded
+    # wire (the schedule span's start to the wire_decode span's end)
+    seconds: float = 0.0
 
 
 def lpt_permutation(cost: jnp.ndarray, n_workers: int) -> jnp.ndarray:
@@ -357,6 +360,7 @@ def _level_program(mmesh: MiningMesh, minsup: int,
             f"the sharded wire needs reduce='reduce_scatter' (each worker "
             f"owns a support slice), got reduce={reduce!r}")
 
+    @jax.named_scope("mirage/wire_pack")
     def _pack_wire(gsup, n_keep, overflow, do_reb, imbal, audit, perm):
         gsup = gsup.astype(jnp.int32)
         if packed:
@@ -378,6 +382,7 @@ def _level_program(mmesh: MiningMesh, minsup: int,
         ])
         return jnp.concatenate([body, wire_checksum(body)[None]])
 
+    @jax.named_scope("mirage/wire_pack")
     def _rebalance(cost):
         NP = cost.shape[0]
         imbal = worker_imbalance(cost, W)
@@ -392,49 +397,54 @@ def _level_program(mmesh: MiningMesh, minsup: int,
         return do_reb, imbal, perm
 
     def core(c_real, psup, *args):
-        if fused:
-            sched_meta, tiles, inv, pol, pmask, src, dst, emask = args
-            if packed:
-                # verdict accumulator = ceil(G/32) uint32 words in VMEM;
-                # local support counting is AND+popcount per tile_c block
-                sup_pp, emb_s = fused_level_supports_packed(
-                    sched_meta, tiles, pol, pmask, src, dst, emask,
-                    interpret=interpret)
+        with jax.named_scope("mirage/support_kernel"):
+            if fused:
+                sched_meta, tiles, inv, pol, pmask, src, dst, emask = args
+                if packed:
+                    # verdict accumulator = ceil(G/32) uint32 words in
+                    # VMEM; local support counting is AND+popcount per
+                    # tile_c block
+                    sup_pp, emb_s = fused_level_supports_packed(
+                        sched_meta, tiles, pol, pmask, src, dst, emask,
+                        interpret=interpret)
+                else:
+                    sup_pp, emb_s = fused_level_supports(
+                        sched_meta, tiles, pol, pmask, src, dst, emask,
+                        interpret=interpret)
+                local_sup = jnp.take(sup_pp.sum(0), inv)    # (Cp,) canonical
+                emb_pp = jnp.take(emb_s, inv, axis=1)       # (PP, Cp)
+                meta_can = jnp.take(sched_meta[:, :5], inv, axis=0)
             else:
-                sup_pp, emb_s = fused_level_supports(
-                    sched_meta, tiles, pol, pmask, src, dst, emask,
-                    interpret=interpret)
-            local_sup = jnp.take(sup_pp.sum(0), inv)        # (Cp,) canonical
-            emb_pp = jnp.take(emb_s, inv, axis=1)           # (PP, Cp)
-            meta_can = jnp.take(sched_meta[:, :5], inv, axis=0)
-        else:
-            meta, pol, pmask, src, dst, emask = args
-            local_sup, _, emb_pp = device_local_supports(
-                meta, pol, pmask, src, dst, emask, backend=backend,
-                packed=packed)
-            meta_can = meta
+                meta, pol, pmask, src, dst, emask = args
+                local_sup, _, emb_pp = device_local_supports(
+                    meta, pol, pmask, src, dst, emask, backend=backend,
+                    packed=packed)
+                meta_can = meta
 
         # sharded: gsup stays the psum_scatter output — this worker's
         # (Cp/W,) key slice, never all-gathered; only the 1-byte
         # verdicts travel the ring (the fig19 wire cut made total) —
         # bit lanes instead when packed.
-        gsup, verdict = reduce_supports(local_sup, axes, minsup, reduce,
-                                        gather_gsup=not sharded,
-                                        packed=packed)
-        Cp = verdict.shape[0]
-        real = jnp.arange(Cp) < c_real
-        keep = (verdict != 0) & real
+        with jax.named_scope("mirage/reduce"):
+            gsup, verdict = reduce_supports(local_sup, axes, minsup, reduce,
+                                            gather_gsup=not sharded,
+                                            packed=packed)
 
         # verdict-masked prefix-sum compaction: survivor i's compact slot
         # is its rank among survivors; one scatter inverts rank -> id.
         # Ranks past the cap S (and non-survivors) scatter out of bounds.
-        rank = jnp.cumsum(keep.astype(jnp.int32)) - 1
-        n_keep = rank[-1] + 1
-        dest = jnp.where(keep, rank, S)
-        surv = (jnp.zeros((S,), jnp.int32)
-                .at[dest].set(jnp.arange(Cp, dtype=jnp.int32), mode="drop"))
-        cmeta = jnp.take(meta_can, surv, axis=0)            # (S, 5)
-        valid_s = jnp.arange(S) < n_keep                    # (S,)
+        with jax.named_scope("mirage/compact"):
+            Cp = verdict.shape[0]
+            real = jnp.arange(Cp) < c_real
+            keep = (verdict != 0) & real
+            rank = jnp.cumsum(keep.astype(jnp.int32)) - 1
+            n_keep = rank[-1] + 1
+            dest = jnp.where(keep, rank, S)
+            surv = (jnp.zeros((S,), jnp.int32)
+                    .at[dest].set(jnp.arange(Cp, dtype=jnp.int32),
+                                  mode="drop"))
+            cmeta = jnp.take(meta_can, surv, axis=0)        # (S, 5)
+            valid_s = jnp.arange(S) < n_keep                # (S,)
 
         # continuous invariant audit (DESIGN.md §14): bit flags over the
         # level's own outputs, folded into the checksummed wire.  psup
@@ -445,39 +455,43 @@ def _level_program(mmesh: MiningMesh, minsup: int,
         # gsup is this worker's key slice, so the slice-local violation
         # counts are psummed; the compaction and survivor-count checks
         # run on replicated values.
-        par = meta_can[:, 0]
-        psc = jnp.where(
-            (par >= 0) & (par < psup.shape[0]),
-            jnp.take(psup, jnp.clip(par, 0, psup.shape[0] - 1)), -1)
-        if sharded:
-            w_idx = jax.lax.axis_index(axes)
-            cs_a = gsup.shape[0]
-            psl = jax.lax.dynamic_slice(psc, (w_idx * cs_a,), (cs_a,))
-            real_a = (w_idx * cs_a + jnp.arange(cs_a)) < c_real
-        else:
-            psl, real_a = psc, real
-        gs_a = gsup.astype(jnp.int32)
-        mono_bad = ((gs_a > psl) & real_a & (psl >= 0)).sum()
-        rng_bad = (((gs_a < 0) | (gs_a > n_graphs)) & real_a).sum() \
-            if n_graphs >= 0 else jnp.zeros((), jnp.int32)
-        if sharded:
-            mono_bad = jax.lax.psum(mono_bad, axes)
-            rng_bad = jax.lax.psum(rng_bad, axes)
-        comp_bad = (valid_s & ~jnp.take(keep, surv)).sum()
-        audit = (jnp.where(mono_bad > 0, AUDIT_MONOTONIC, 0)
-                 | jnp.where(comp_bad > 0, AUDIT_COMPACT, 0)
-                 | jnp.where(rng_bad > 0, AUDIT_RANGE, 0)
-                 | jnp.where(n_keep > c_real, AUDIT_NKEEP, 0)
-                 ).astype(jnp.int32)
+        with jax.named_scope("mirage/audit"):
+            par = meta_can[:, 0]
+            psc = jnp.where(
+                (par >= 0) & (par < psup.shape[0]),
+                jnp.take(psup, jnp.clip(par, 0, psup.shape[0] - 1)), -1)
+            if sharded:
+                w_idx = jax.lax.axis_index(axes)
+                cs_a = gsup.shape[0]
+                psl = jax.lax.dynamic_slice(psc, (w_idx * cs_a,), (cs_a,))
+                real_a = (w_idx * cs_a + jnp.arange(cs_a)) < c_real
+            else:
+                psl, real_a = psc, real
+            gs_a = gsup.astype(jnp.int32)
+            mono_bad = ((gs_a > psl) & real_a & (psl >= 0)).sum()
+            rng_bad = (((gs_a < 0) | (gs_a > n_graphs)) & real_a).sum() \
+                if n_graphs >= 0 else jnp.zeros((), jnp.int32)
+            if sharded:
+                mono_bad = jax.lax.psum(mono_bad, axes)
+                rng_bad = jax.lax.psum(rng_bad, axes)
+            comp_bad = (valid_s & ~jnp.take(keep, surv)).sum()
+            audit = (jnp.where(mono_bad > 0, AUDIT_MONOTONIC, 0)
+                     | jnp.where(comp_bad > 0, AUDIT_COMPACT, 0)
+                     | jnp.where(rng_bad > 0, AUDIT_RANGE, 0)
+                     | jnp.where(n_keep > c_real, AUDIT_NKEEP, 0)
+                     ).astype(jnp.int32)
 
         # pass 2 over the valid compact slots only: cap padding keeps
         # the constant fill and costs nothing
-        Wk = child_width if child_width is not None else pol.shape[-1] + 1
-        ol, mask, over = materialize_prefix(
-            cmeta, jnp.minimum(n_keep, S), pol, pmask, src, dst, emask,
-            n_slots=S, max_embeddings=max_embeddings, out_width=Wk)
-        overflow = jax.lax.psum(over, axes)
-        cost_pp = (emb_pp * real[None, :].astype(emb_pp.dtype)).sum(1)
+        with jax.named_scope("mirage/materialize"):
+            Wk = (child_width if child_width is not None
+                  else pol.shape[-1] + 1)
+            ol, mask, over = materialize_prefix(
+                cmeta, jnp.minimum(n_keep, S), pol, pmask, src, dst, emask,
+                n_slots=S, max_embeddings=max_embeddings, out_width=Wk)
+            overflow = jax.lax.psum(over, axes)
+        with jax.named_scope("mirage/wire_pack"):
+            cost_pp = (emb_pp * real[None, :].astype(emb_pp.dtype)).sum(1)
         if not sharded:
             return gsup, n_keep, overflow, audit, ol, mask, cost_pp
         # sharded wire: the LPT/rebalance decision moves inside the
@@ -485,7 +499,8 @@ def _level_program(mmesh: MiningMesh, minsup: int,
         # vector), and each worker packs its own shard — support slice,
         # replicated scalars + perm, per-shard checksum.  The level's
         # device→host transfer is then 1/W-sized per worker.
-        cost = jax.lax.all_gather(cost_pp, axes, axis=0, tiled=True)
+        with jax.named_scope("mirage/wire_pack"):
+            cost = jax.lax.all_gather(cost_pp, axes, axis=0, tiled=True)
         do_reb, imbal, perm = _rebalance(cost)
         shard = _pack_wire(gsup, n_keep, overflow, do_reb, imbal, audit,
                            perm)
@@ -528,6 +543,7 @@ def _permute_program(mmesh: MiningMesh):
     the repack replaces the store wholesale."""
     shard = NamedSharding(mmesh.mesh, mmesh.spec_parts())
 
+    @jax.named_scope("mirage/permute")
     def permute(perm, *arrays):
         return tuple(jax.lax.with_sharding_constraint(
             jnp.take(a, perm, axis=0), shard) for a in arrays)
@@ -539,7 +555,9 @@ def permute_stores(mmesh: MiningMesh, perm: np.ndarray, *arrays):
     """Apply the level's LPT permutation to (pol, pmask, src, dst,
     emask) on device.  No host transfer — ``perm`` came home in the
     wire."""
-    return _permute_program(mmesh)(jnp.asarray(perm, jnp.int32), *arrays)
+    with tracing.Span("permute"):
+        return _permute_program(mmesh)(jnp.asarray(perm, jnp.int32),
+                                       *arrays)
 
 
 def _fetch_wire(wire_d, level: Optional[int], n_partitions: int,
@@ -557,6 +575,7 @@ def _fetch_wire(wire_d, level: Optional[int], n_partitions: int,
     :class:`~repro.runtime.faults.WireIntegrityError` for the supervisor
     rather than ever decoding corrupt supports."""
     for _ in range(_WIRE_FETCH_ATTEMPTS):
+        tracing.count("wire_fetches")
         host = faults.corrupt_wire(np.array(wire_d), level)
         body = reassemble_wire(host, n_partitions, n_shards,
                                packed=packed, cp=cp)
@@ -612,15 +631,23 @@ class PendingLevel:
     n_shards: int              # 1 = dense wire; W = sharded
     level: Optional[int]
     packed: bool = False       # gsup slices ship 2x uint16 per word
+    start_ns: int = 0          # the dispatch's schedule span opened
 
     def finish(self) -> LevelOutputs:
-        """Block on the wire (the one host sync), verify + decode it."""
-        wire = unpack_wire(
-            _fetch_wire(self.wire_d, self.level, self.n_partitions,
-                        self.n_shards, self.packed, self.Cp),
-            self.C_real, self.Cp, self.n_partitions)
+        """Block on the wire (the one host sync), verify + decode it.
+        The wait for the device and the transfer are separate spans, so
+        a stall in the transfer is not put down to the device."""
+        with tracing.Span("wire_wait"):
+            self.wire_d.block_until_ready()
+        with tracing.Span("wire_decode",
+                          counts={"attempts": "wire_fetches"}) as dec:
+            wire = unpack_wire(
+                _fetch_wire(self.wire_d, self.level, self.n_partitions,
+                            self.n_shards, self.packed, self.Cp),
+                self.C_real, self.Cp, self.n_partitions)
         return LevelOutputs(wire, self.pol, self.pmask, self.src,
-                            self.dst, self.emask)
+                            self.dst, self.emask,
+                            (dec.end_ns - self.start_ns) / 1e9)
 
 
 def dispatch_level(
@@ -692,51 +719,56 @@ def dispatch_level(
     # an XLA/Mosaic dispatch abort (the supervisor's degradation ladder
     # answers it by swapping backends)
     faults.maybe_raise("kernel", level)
-    fn = _level_program(mmesh, minsup, backend, reduce,
-                        max_embeddings, survivor_cap, rebalance,
-                        threshold, donate, child_width, sharded, packed,
-                        n_graphs)
-    c_real = jnp.asarray(C_real, jnp.int32)
-    # pad to the parent store's pattern axis: the psup length then moves
-    # with the same bucket family as pol, costing no extra compiles
-    P_axis = pol.shape[1]
-    psup_p = np.full((P_axis,), -1, np.int32)
-    if psup is not None:
-        n_par = min(len(psup), P_axis)
-        psup_p[:n_par] = np.asarray(psup, np.int32)[:n_par]
-    psup_d = jnp.asarray(psup_p)
-    if is_fused_backend(backend):
-        from ..kernels.fused_level import DEFAULT_TILE_C
-        from .buckets import bucket_size
-        from .candgen import pad_schedule, schedule_candidates
-        tc = tile_c if tile_c is not None else DEFAULT_TILE_C
-        # only the real rows are scheduled (padded candidates would
-        # fragment the parent grouping); the row axis is then bucketed
-        # with whole invalid tiles and inv parked on one of them.  The
-        # bucketed schedule PINS tile_c: the adaptive halving picks a
-        # different width per level (a different kernel grid — a
-        # recompile); partial-tile waste is bounded by the row bucket
-        # and fully-invalid tiles are skipped inside the kernel.  The
-        # driver's run-level pin (``tile_c``) replaces the hardwired 8
-        # with the level-2 grouping's adaptive choice.
-        if sched_floor is not None:
-            sched = schedule_candidates(np.asarray(meta_p)[:C_real], tc,
-                                        max_inflation=float("inf"))
-            rows = bucket_size(sched.meta.shape[0], sched_floor)
+    with tracing.Span("schedule") as sched_span:
+        fn = _level_program(mmesh, minsup, backend, reduce,
+                            max_embeddings, survivor_cap, rebalance,
+                            threshold, donate, child_width, sharded, packed,
+                            n_graphs)
+        c_real = jnp.asarray(C_real, jnp.int32)
+        # pad to the parent store's pattern axis: the psup length then
+        # moves with the same bucket family as pol, costing no extra
+        # compiles
+        P_axis = pol.shape[1]
+        psup_p = np.full((P_axis,), -1, np.int32)
+        if psup is not None:
+            n_par = min(len(psup), P_axis)
+            psup_p[:n_par] = np.asarray(psup, np.int32)[:n_par]
+        psup_d = jnp.asarray(psup_p)
+        if is_fused_backend(backend):
+            from ..kernels.fused_level import DEFAULT_TILE_C
+            from .buckets import bucket_size
+            from .candgen import pad_schedule, schedule_candidates
+            tc = tile_c if tile_c is not None else DEFAULT_TILE_C
+            # only the real rows are scheduled (padded candidates would
+            # fragment the parent grouping); the row axis is then
+            # bucketed with whole invalid tiles and inv parked on one of
+            # them.  The bucketed schedule PINS tile_c: the adaptive
+            # halving picks a different width per level (a different
+            # kernel grid — a recompile); partial-tile waste is bounded
+            # by the row bucket and fully-invalid tiles are skipped
+            # inside the kernel.  The mining loop's run-level pin
+            # (``tile_c``) replaces the hardwired 8 with the level-2
+            # grouping's adaptive choice.
+            if sched_floor is not None:
+                sched = schedule_candidates(np.asarray(meta_p)[:C_real], tc,
+                                            max_inflation=float("inf"))
+                rows = bucket_size(sched.meta.shape[0], sched_floor)
+            else:
+                sched = schedule_candidates(np.asarray(meta_p)[:C_real], tc)
+                rows = sched.meta.shape[0]
+            sched = pad_schedule(sched, rows_to=rows, inv_to=Cp)
+            sched_span.set(rows=rows, tile_c=sched.tile_c)
+            meta_args = (jnp.asarray(sched.meta), jnp.asarray(sched.tiles),
+                         jnp.asarray(sched.inv))
         else:
-            sched = schedule_candidates(np.asarray(meta_p)[:C_real], tc)
-            rows = sched.meta.shape[0]
-        sched = pad_schedule(sched, rows_to=rows, inv_to=Cp)
-        out = fn(c_real, psup_d, jnp.asarray(sched.meta),
-                 jnp.asarray(sched.tiles), jnp.asarray(sched.inv),
-                 pol, pmask, src, dst, emask)
-    else:
-        out = fn(c_real, psup_d, jnp.asarray(meta_p), pol, pmask, src,
-                 dst, emask)
-    wire_d, new_pol, new_pmask = out
+            meta_args = (jnp.asarray(meta_p),)
+    with tracing.Span("dispatch", counts={"compiles": "compiles"}):
+        wire_d, new_pol, new_pmask = fn(c_real, psup_d, *meta_args,
+                                        pol, pmask, src, dst, emask)
     return PendingLevel(wire_d, new_pol, new_pmask, src, dst, emask,
                         C_real, Cp, n_partitions,
-                        W if sharded else 1, level, packed)
+                        W if sharded else 1, level, packed,
+                        sched_span.start_ns)
 
 
 def run_level(*args, **kwargs) -> LevelOutputs:

@@ -248,6 +248,7 @@ def _materialize_program(mmesh: MiningMesh, max_embeddings: int,
     parts = mmesh.spec_parts()
     rep = mmesh.replicated()
 
+    @jax.named_scope("mirage/materialize")
     def program(meta, pol, pmask, src, dst, emask):
         n = meta.shape[0]
         width = out_width if out_width is not None else pol.shape[-1] + 1

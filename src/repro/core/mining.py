@@ -45,7 +45,6 @@ replacing Hadoop's speculative execution.
 from __future__ import annotations
 
 import dataclasses
-import time
 from typing import Optional, Sequence
 
 import jax
@@ -55,7 +54,7 @@ import numpy as np
 from ..kernels.fused_level import LANES
 from ..kernels.ops import Backend, default_backend, is_fused_backend
 from ..runtime import checkpoint as ckpt
-from ..runtime import faults
+from ..runtime import faults, tracing
 from ..runtime.sharding import partition_sharding
 from ..runtime.watchdog import Watchdog
 from . import device_loop as dloop
@@ -378,6 +377,10 @@ class _LevelOutcome:
     # device audit word from the wire (0 = every invariant held; the
     # legacy pipeline computes no word and always reports 0)
     audit: int = 0
+    donated: bool = False       # the parent store was donated
+    # the speculation gate's decision: "taken", "skipped" (the estimate
+    # outran its window) or "none" (no speculation attempted)
+    spec: str = "none"
 
 
 class Mirage:
@@ -423,6 +426,23 @@ class Mirage:
     def fit(self, graphs: Sequence[Graph], *, resume: bool = False,
             watchdog: Optional[Watchdog] = None,
             deadline_s: Optional[float] = None) -> DistMiningResult:
+        """Mine ``graphs``; the run is the ``mirage:fit`` span, its
+        phases the spans of ``runtime/tracing.py``."""
+        cfg = self.cfg
+        with tracing.Span("fit", counts=tracing.FIT_COUNTS,
+                          n_graphs=len(graphs), minsup=cfg.minsup,
+                          pipeline=cfg.pipeline) as run:
+            result = self._fit(graphs, resume=resume, watchdog=watchdog,
+                               deadline_s=deadline_s)
+            run.set(levels=len(result.levels))
+            return result
+
+    # the paper's verb; the supervisor wraps this entrypoint
+    mine = fit
+
+    def _fit(self, graphs: Sequence[Graph], *, resume: bool,
+             watchdog: Optional[Watchdog],
+             deadline_s: Optional[float]) -> DistMiningResult:
         cfg = self.cfg
 
         # peek the checkpoint first: the partition count is baked into
@@ -448,8 +468,9 @@ class Mirage:
                     f"{self.mesh.n_workers} — resume on a compatible mesh")
         else:
             n_parts = self._effective_partitions(len(graphs))
-        part = make_partitions(graphs, cfg.minsup, n_parts,
-                               scheme=cfg.scheme)
+        with tracing.Span("partition", n_parts=n_parts):
+            part = make_partitions(graphs, cfg.minsup, n_parts,
+                                   scheme=cfg.scheme)
         alphabet, minsup = part.alphabet, part.minsup
         triples = sorted({t for c in alphabet.canonical()
                           for t in (c, (c[2], c[1], c[0]))})
@@ -485,12 +506,15 @@ class Mirage:
             # stay bitcasts (padded graphs carry all-False masks)
             G = round_up_multiple(G, LANES)
         self._graphs_axis = G
-        eols = [build_edge_ol(p, triples, pad_graphs=G, max_occ=cfg.max_occ)
-                for p in part.partitions]
-        F = max(e.src.shape[-1] for e in eols)
-        src = np.stack([_pad_f(e.src, F, -1) for e in eols])       # (NP,T,G,F)
-        dst = np.stack([_pad_f(e.dst, F, -1) for e in eols])
-        emask = np.stack([_pad_f(e.mask, F, False) for e in eols])
+        with tracing.Span("edge_ol_build") as sp:
+            eols = [build_edge_ol(p, triples, pad_graphs=G,
+                                  max_occ=cfg.max_occ)
+                    for p in part.partitions]
+            F = max(e.src.shape[-1] for e in eols)
+            src = np.stack([_pad_f(e.src, F, -1) for e in eols])   # (NP,T,G,F)
+            dst = np.stack([_pad_f(e.dst, F, -1) for e in eols])
+            emask = np.stack([_pad_f(e.mask, F, False) for e in eols])
+            sp.set(F=F)
         eol0 = eols[0]   # triple_index identical across partitions
 
         codes = [((0, 1, a, e, b),) for (a, e, b) in alphabet.canonical()]
@@ -500,21 +524,22 @@ class Mirage:
         M1 = max(cfg.max_embeddings, F)
         if bk is not None:
             M1 = bk.embeddings(M1, cfg.max_embeddings)
-        lvl1 = [level1_ol(codes, e, max_embeddings=M1) for e in eols]
-        pol = np.stack([np.asarray(l.ol) for l in lvl1])           # (NP,P,G,M,2)
-        pmask = np.stack([np.asarray(l.mask) for l in lvl1])
-        if bk is not None:
-            # bucket the level-1 store into the same (P, K) family the
-            # child stores live in, so the level-2 program is often THE
-            # program every later level reuses
-            pol, pmask = _pad_store(
-                pol, pmask, p_to=bucket_size(len(codes), bk.s_floor),
-                k_to=bk.vertex_slots(2))
+        with tracing.Span("level1", codes=len(codes)):
+            lvl1 = [level1_ol(codes, e, max_embeddings=M1) for e in eols]
+            pol = np.stack([np.asarray(l.ol) for l in lvl1])   # (NP,P,G,M,2)
+            pmask = np.stack([np.asarray(l.mask) for l in lvl1])
+            if bk is not None:
+                # bucket the level-1 store into the same (P, K) family
+                # the child stores live in, so the level-2 program is
+                # often THE program every later level reuses
+                pol, pmask = _pad_store(
+                    pol, pmask, p_to=bucket_size(len(codes), bk.s_floor),
+                    k_to=bk.vertex_slots(2))
 
-        supports: dict[Code, int] = {}
-        for c in codes:
-            ti = eol0.triple_index[c[0][2:]]
-            supports[c] = int(emask[:, ti].any(axis=-1).sum())
+            supports: dict[Code, int] = {}
+            for c in codes:
+                ti = eol0.triple_index[c[0][2:]]
+                supports[c] = int(emask[:, ti].any(axis=-1).sum())
         levels: list[list[Code]] = [list(codes)]
         stats: list[LevelStats] = []
         total_overflow = 0
@@ -561,11 +586,12 @@ class Mirage:
         # ---- device-resident whole-run loop (DESIGN.md §13) ------------
         if cfg.pipeline == "device_loop" and start_level < cfg.max_size:
             try:
-                return self._mine_device_loop(
-                    alphabet, minsup, triples, eol0, levels, supports,
-                    pol, pmask, src_d, dst_d, emask_d, packed=packed,
-                    start_k=start_level, total_overflow=total_overflow,
-                    order=order)
+                with tracing.Span("device_loop") as run:
+                    return self._mine_device_loop(
+                        alphabet, minsup, triples, eol0, levels, supports,
+                        pol, pmask, src_d, dst_d, emask_d, packed=packed,
+                        start_k=start_level, total_overflow=total_overflow,
+                        order=order, run=run)
             except dloop.DeviceLoopFallback as bail:
                 # a static budget tripped (or the M valve hit its
                 # ceiling): replay the run through the per-level
@@ -579,157 +605,178 @@ class Mirage:
         k = start_level
         # overlapped candgen (DESIGN.md §11): each single-sync level
         # speculatively generates the NEXT level's candidates while its
-        # device program is in flight; the narrowed result carries over
-        # here so the loop head only regenerates when no speculation ran
+        # device program is in flight; the loop head narrows them to the
+        # survivors and only regenerates when no speculation ran.  A
+        # replayed level keeps its ``cands``.
         cands: Optional[list[Candidate]] = None
+        spec: Optional[tuple[list[Candidate], np.ndarray]] = None
         # speculation cost gate inputs (see overlap_spec_window): EWMA
         # per-parent candgen rate, sampled from EVERY generation (fresh
         # and speculative), and the last level's device-only seconds
         cand_rate: Optional[float] = None
         prev_dev = 0.0
         while cfg.max_size is None or k < cfg.max_size:
-            t0 = time.perf_counter()
-            if wd is not None:
-                # cooperative run-deadline check at the loop head — the
-                # only place a DeadlineExceeded can safely unwind from
-                wd.check_run(level=k + 1)
-            if cands is None and cfg.candgen == "device":
-                # the stepping-stone device candgen: one jitted
-                # device_candidates dispatch instead of the host
-                # generator (None = per-level budget overflow → fall
-                # back to the host generator for this level only)
-                cands = self._device_candgen(levels[-1], triples)
-            if cands is None:
-                cands = generate_candidates(levels[-1], alphabet)
-                if levels[-1]:
-                    r = (time.perf_counter() - t0) / len(levels[-1])
+            with tracing.Span("level", counts={"compiles": "compiles"},
+                              level=k + 1) as lvl:
+                if wd is not None:
+                    # cooperative run-deadline check at the loop head — the
+                    # only place a DeadlineExceeded can safely unwind from
+                    wd.check_run(level=k + 1)
+                if cands is None:
+                    with tracing.Span("candgen",
+                                      parents=len(levels[-1])) as cg:
+                        fresh = False
+                        if spec is not None:
+                            # provably equal to generate_candidates(F_{k+1}),
+                            # see filter_speculative
+                            cands = filter_speculative(*spec)
+                        elif cfg.candgen == "device":
+                            # the stepping-stone device candgen: one jitted
+                            # device_candidates dispatch instead of the host
+                            # generator (None = per-level budget overflow →
+                            # fall back to the host generator for this level)
+                            cands = self._device_candgen(levels[-1], triples)
+                        if cands is None:
+                            cands = generate_candidates(levels[-1], alphabet)
+                            fresh = True
+                        cg.set(candidates=len(cands))
+                    if fresh and levels[-1]:
+                        r = cg.seconds / len(levels[-1])
+                        cand_rate = (r if cand_rate is None
+                                     else 0.5 * (cand_rate + r))
+                if not cands:
+                    break
+                # chaos hook: a scheduled worker death at this level
+                faults.maybe_raise("level_start", k + 1)
+                n_parents = len(levels[-1])
+                with tracing.Span("candidate_meta"):
+                    meta = candidate_meta(cands, eol0)
+                    C = meta.shape[0]
+                    W = self.mesh.n_workers
+                    Cp = (bk.candidates(C, W) if bk is not None
+                          else round_up_multiple(C, W))
+                    meta_p = np.concatenate(
+                        [meta, np.tile([[0, 0, 0, 1, 0]], (Cp - C, 1))]
+                    ).astype(np.int32)
+
+                    # parent supports for the device audit word (§14): one
+                    # int32 per parent pattern, indexed on device through the
+                    # meta parent column (-1 = unknown, e.g. a resumed run
+                    # whose map predates the parent) — monotonicity
+                    # gsup <= psup[parent] is anti-monotone pruning's invariant
+                    psup = None
+                    if cfg.audit and cfg.pipeline != "legacy":
+                        psup = np.array([supports.get(p, -1)
+                                         for p in levels[-1]], np.int32)
+                lvl.set(candidates=C, Cp=Cp)
+                if wd is not None:
+                    # arm the phase deadline around the device dispatch —
+                    # the stretch a hang would otherwise block unobserved
+                    wd.arm(level=k + 1)
+
+                if cfg.pipeline == "legacy":
+                    out = self._level_legacy(
+                        meta_p, meta, C, pol, pmask, src_d, dst_d, emask_d,
+                        minsup, M, n_parts, level=k + 1)
+                else:
+                    # child patterns (size k+1) have at most k+2 vertices;
+                    # the bucketed width reuses the parent store's while the
+                    # child still fits, so the arena shape repeats
+                    child_width = (bk.vertex_slots(k + 2, int(pol.shape[-1]))
+                                   if bk is not None else None)
+                    if (tile_pin is None and bk is not None
+                            and is_fused_backend(cfg.backend)):
+                        # level 2 is the widest, most parent-diverse grouping
+                        # the run will see — its adaptive choice generalizes;
+                        # later levels reuse it so the schedule shapes (and
+                        # the compiled level program) stay fixed
+                        with tracing.Span("schedule") as sp:
+                            tile_pin = schedule_candidates(meta).tile_c
+                            sp.set(tile_c=tile_pin)
+                    try:
+                        out = self._level_single_sync(
+                            meta_p, meta, C, pol, pmask, src_d, dst_d, emask_d,
+                            minsup, M, history, child_width,
+                            level=k + 1, policy=policy,
+                            packed=packed, tile_c=tile_pin,
+                            cands=cands, alphabet=alphabet,
+                            cand_rate=cand_rate,
+                            spec_window=max(prev_dev,
+                                            cfg.overlap_spec_window),
+                            psup=psup, n_graphs=n_graphs)
+                    except DonationRetryRebuild:
+                        # the armed-donation gamble lost: the arena consumed
+                        # the parents, so restore them from the latest intact
+                        # checkpoint (canonical store re-padded + cumulative
+                        # rebalance permutation re-applied) and replay
+                        if wd is not None:
+                            wd.disarm()
+                        pol, pmask = self._rebuild_parents(order)
+                        policy.record_rebuild()
+                        continue
+                    policy.record(out.retried)
+                lvl.set(S=out.survivor_cap, M=out.max_embeddings,
+                        donated=int(out.donated), retried=int(out.retried),
+                        escalations=out.escalations, spec=out.spec)
+                if wd is not None:
+                    # feed the level's wall-time into the EWMA the next
+                    # phase deadline is derived from
+                    wd.disarm(observe_s=lvl.elapsed())
+                if self.auditor is not None:
+                    with tracing.Span("audit"):
+                        self.auditor.check_wire(k + 1, out.audit)
+                        if len(out.keep):
+                            self.auditor.check_level(
+                                k + 1, cands=cands, keep=out.keep,
+                                gsup=out.gsup, parents=levels[-1],
+                                supports=supports)
+                prev_dev = max(out.map_seconds - out.candgen_seconds, 0.0)
+                if out.spec_cands is not None and cands:
+                    r = out.candgen_seconds / len(cands)
                     cand_rate = (r if cand_rate is None
                                  else 0.5 * (cand_rate + r))
-            if not cands:
-                break
-            # chaos hook: a scheduled worker death at this level
-            faults.maybe_raise("level_start", k + 1)
-            n_parents = len(levels[-1])
-            meta = candidate_meta(cands, eol0)
-            C = meta.shape[0]
-            Cp = (bk.candidates(C, self.mesh.n_workers) if bk is not None
-                  else round_up_multiple(C, self.mesh.n_workers))
-            meta_p = np.concatenate(
-                [meta, np.tile([[0, 0, 0, 1, 0]], (Cp - C, 1))]).astype(np.int32)
+                M = out.max_embeddings
+                total_overflow += out.overflow
 
-            # parent supports for the device audit word (§14): one
-            # int32 per parent pattern, indexed on device through the
-            # meta parent column (-1 = unknown, e.g. a resumed run
-            # whose map predates the parent) — monotonicity
-            # gsup <= psup[parent] is anti-monotone pruning's invariant
-            psup = None
-            if cfg.audit and cfg.pipeline != "legacy":
-                psup = np.array(
-                    [supports.get(p, -1) for p in levels[-1]], np.int32)
-            if wd is not None:
-                # arm the phase deadline around the device dispatch —
-                # the stretch a hang would otherwise block unobserved
-                wd.arm(level=k + 1)
+                if len(out.keep) == 0:
+                    stats.append(LevelStats(
+                        k + 1, C, 0, out.overflow, lvl.elapsed(),
+                        out.map_seconds, False, out.imbalance,
+                        out.escalations, out.candgen_seconds,
+                        survivor_cap=out.survivor_cap, retried=out.retried))
+                    break
 
-            if cfg.pipeline == "legacy":
-                out = self._level_legacy(
-                    meta_p, meta, C, pol, pmask, src_d, dst_d, emask_d,
-                    minsup, M, n_parts, level=k + 1)
-            else:
-                # child patterns (size k+1) have at most k+2 vertices;
-                # the bucketed width reuses the parent store's while the
-                # child still fits, so the arena shape repeats
-                child_width = (bk.vertex_slots(k + 2, int(pol.shape[-1]))
-                               if bk is not None else None)
-                if (tile_pin is None and bk is not None
-                        and is_fused_backend(cfg.backend)):
-                    # level 2 is the widest, most parent-diverse grouping
-                    # the run will see — its adaptive choice generalizes;
-                    # later levels reuse it so the schedule shapes (and
-                    # the compiled level program) stay fixed
-                    tile_pin = schedule_candidates(meta).tile_c
-                try:
-                    out = self._level_single_sync(
-                        meta_p, meta, C, pol, pmask, src_d, dst_d, emask_d,
-                        minsup, M, history, child_width,
-                        level=k + 1, policy=policy,
-                        packed=packed, tile_c=tile_pin,
-                        cands=cands, alphabet=alphabet,
-                        cand_rate=cand_rate,
-                        spec_window=max(prev_dev,
-                                        cfg.overlap_spec_window),
-                        psup=psup, n_graphs=n_graphs)
-                except DonationRetryRebuild:
-                    # the armed-donation gamble lost: the arena consumed
-                    # the parents, so restore them from the latest intact
-                    # checkpoint (canonical store re-padded + cumulative
-                    # rebalance permutation re-applied) and replay
-                    if wd is not None:
-                        wd.disarm()
-                    pol, pmask = self._rebuild_parents(order)
-                    policy.record_rebuild()
-                    continue
-                policy.record(out.retried)
-            if wd is not None:
-                # feed the level's wall-time into the EWMA the next
-                # phase deadline is derived from
-                wd.disarm(observe_s=time.perf_counter() - t0)
-            if self.auditor is not None:
-                self.auditor.check_wire(k + 1, out.audit)
-                if len(out.keep):
-                    self.auditor.check_level(
-                        k + 1, cands=cands, keep=out.keep, gsup=out.gsup,
-                        parents=levels[-1], supports=supports)
-            prev_dev = max(out.map_seconds - out.candgen_seconds, 0.0)
-            if out.spec_cands is not None and cands:
-                r = out.candgen_seconds / len(cands)
-                cand_rate = (r if cand_rate is None
-                             else 0.5 * (cand_rate + r))
-            M = out.max_embeddings
-            total_overflow += out.overflow
+                pol, pmask = out.pol, out.pmask
+                src_d, dst_d, emask_d = out.src, out.dst, out.emask
+                levels.append([cands[i].code for i in out.keep])
+                for i in out.keep:
+                    supports[cands[i].code] = int(out.gsup[i])
+                if out.perm is not None:
+                    order = order[out.perm]
+                history.append((n_parents, C, len(out.keep)))
 
-            if len(out.keep) == 0:
-                stats.append(LevelStats(k + 1, C, 0, out.overflow,
-                                        time.perf_counter() - t0,
-                                        out.map_seconds, False, out.imbalance,
-                                        out.escalations, out.candgen_seconds,
+                stats.append(LevelStats(k + 1, C, len(out.keep), out.overflow,
+                                        lvl.elapsed(),
+                                        out.map_seconds, out.rebalanced,
+                                        out.imbalance, out.escalations,
+                                        out.candgen_seconds,
                                         survivor_cap=out.survivor_cap,
                                         retried=out.retried))
-                break
 
-            pol, pmask = out.pol, out.pmask
-            src_d, dst_d, emask_d = out.src, out.dst, out.emask
-            levels.append([cands[i].code for i in out.keep])
-            for i in out.keep:
-                supports[cands[i].code] = int(out.gsup[i])
-            if out.perm is not None:
-                order = order[out.perm]
-            history.append((n_parents, C, len(out.keep)))
-
-            stats.append(LevelStats(k + 1, C, len(out.keep), out.overflow,
-                                    time.perf_counter() - t0,
-                                    out.map_seconds, out.rebalanced,
-                                    out.imbalance, out.escalations,
-                                    out.candgen_seconds,
-                                    survivor_cap=out.survivor_cap,
-                                    retried=out.retried))
-
-            if cfg.checkpoint_dir:
-                self._save(cfg.checkpoint_dir, k + 1, levels, supports,
-                           pol, pmask, M, total_overflow, order)
-                policy.can_rebuild = True
-            # narrow this level's speculative superset (generated from
-            # ALL candidates) to the surviving parents — provably equal
-            # to generate_candidates(F_{k+1}), see filter_speculative
-            cands = (filter_speculative(out.spec_cands, out.keep)
-                     if out.spec_cands is not None else None)
-            k += 1
+                if cfg.checkpoint_dir:
+                    self._save(cfg.checkpoint_dir, k + 1, levels, supports,
+                               pol, pmask, M, total_overflow, order)
+                    policy.can_rebuild = True
+                # this level's speculative superset (generated from ALL
+                # candidates) is narrowed to the surviving parents at the
+                # next loop head
+                cands = None
+                spec = ((out.spec_cands, out.keep)
+                        if out.spec_cands is not None else None)
+                k += 1
 
         return DistMiningResult(levels, supports, stats, alphabet, minsup,
                                 total_overflow)
-
-    # the paper's verb; the supervisor wraps this entrypoint
-    mine = fit
 
     # ------------------------------------------------------------------
     def _repad_saved(self, pol, pmask):
@@ -945,7 +992,8 @@ class Mirage:
     def _mine_device_loop(self, alphabet, minsup, triples, eol0, levels0,
                           supports0, pol, pmask, src, dst, emask, *,
                           packed: bool, start_k: int, total_overflow: int,
-                          order: np.ndarray) -> DistMiningResult:
+                          order: np.ndarray,
+                          run: tracing.Span) -> DistMiningResult:
         """The whole run as ONE jitted ``lax.while_loop`` program
         (core/device_loop.py, DESIGN.md §13).
 
@@ -969,7 +1017,6 @@ class Mirage:
         bk = self._buckets()
         W = self.mesh.n_workers
         backend = cfg.backend or default_backend()
-        t0 = time.perf_counter()
         L = cfg.max_size
         NL = L - 1
         NV = bk.vertex_slots(L + 1)
@@ -1032,29 +1079,30 @@ class Mirage:
                     # the phase deadline re-arms over the coming chunk
                     wd.check_run(level=k_stop)
                     wd.arm(level=k_stop)
-                t_chunk = time.perf_counter()
-                for lv in range(k_cur + 1, k_stop + 1):
-                    # chaos hooks, fired host-side per window level so
-                    # fault schedules hit device-loop runs too
-                    faults.maybe_raise("level_start", lv)
-                    faults.maybe_raise("kernel", lv)
-                calls = (1 if cfg.device_loop_unroll <= 0 else
-                         -(-(k_stop - k_cur) // cfg.device_loop_unroll))
-                for _ in range(calls):
-                    out = prog(jnp.int32(k_stop), *carry)
-                    carry = (out[1], out[2], out[3], trip_a, out[4],
-                             out[5], src, dst, emask, out[6], out[7],
-                             out[8], out[9], out[10])
-                chunks += 1
-                # chaos hook: a stalled chunk — the armed phase deadline
-                # (and the device_loop→single_sync rung) bounds it
-                faults.maybe_hang("chunk", k_stop, wd)
-                # the chunk boundary's (only) host contact
-                body = fetch_wire(out[0], level=k_stop)
-                rw = dloop.decode_run_wire(body, NL, SPP, L)
+                with tracing.Span("chunk", level=k_stop) as chunk:
+                    for lv in range(k_cur + 1, k_stop + 1):
+                        # chaos hooks, fired host-side per window level
+                        # so fault schedules hit device-loop runs too
+                        faults.maybe_raise("level_start", lv)
+                        faults.maybe_raise("kernel", lv)
+                    calls = (1 if cfg.device_loop_unroll <= 0 else
+                             -(-(k_stop - k_cur) // cfg.device_loop_unroll))
+                    for _ in range(calls):
+                        out = prog(jnp.int32(k_stop), *carry)
+                        carry = (out[1], out[2], out[3], trip_a, out[4],
+                                 out[5], src, dst, emask, out[6], out[7],
+                                 out[8], out[9], out[10])
+                    chunks += 1
+                    # chaos hook: a stalled chunk — the armed phase
+                    # deadline (and the device_loop→single_sync rung)
+                    # bounds it
+                    faults.maybe_hang("chunk", k_stop, wd)
+                    # the chunk boundary's (only) host contact
+                    body = fetch_wire(out[0], level=k_stop)
+                    rw = dloop.decode_run_wire(body, NL, SPP, L)
                 k_cur = k_stop
                 if wd is not None:
-                    wd.disarm(observe_s=time.perf_counter() - t_chunk)
+                    wd.disarm(observe_s=chunk.seconds)
                 if not rw.ok:
                     break
                 # overflow first: a run that reached its fixpoint
@@ -1102,8 +1150,7 @@ class Mirage:
         if self.auditor is not None:
             self.auditor.check_levels(levels, sups)
         tovf = total_overflow + rw.total_overflow
-        elapsed = time.perf_counter() - t0
-        per = elapsed / max(len(rows), 1)
+        per = run.elapsed() / max(len(rows), 1)
         stats = [LevelStats(lv, nc, nk, ov, per, per, False, imb,
                             escalations if i == 0 else 0,
                             survivor_cap=SPP)
@@ -1176,7 +1223,6 @@ class Mirage:
                                and M < cfg.max_embeddings_limit))
         donated = cfg.donate and (not may_retry
                                   or (policy is not None and policy.armed))
-        t_map = time.perf_counter()
         pending = dispatch_level(
             self.mesh, meta_p, C, pol, pmask, src, dst, emask,
             minsup=minsup, backend=backend, reduce=cfg.reduce,
@@ -1195,18 +1241,21 @@ class Mirage:
         # is free — speculate the next level's candidates now
         spec_cands = None
         cand_secs = 0.0
+        spec = "none"
         if cfg.overlap_candgen and cands is not None and alphabet is not None:
             window = (cfg.overlap_spec_window if spec_window is None
                       else spec_window)
             est = (cand_rate or 0.0) * len(cands)
+            spec = "skipped"
             if est <= window:
-                t_cand = time.perf_counter()
-                spec_cands = generate_candidates([c.code for c in cands],
-                                                 alphabet)
-                cand_secs = time.perf_counter() - t_cand
+                spec = "taken"
+                with tracing.Span("candgen_spec", est_s=est,
+                                  window_s=window) as sp:
+                    spec_cands = generate_candidates(
+                        [c.code for c in cands], alphabet)
+                cand_secs = sp.seconds
         out = pending.finish()
         w = out.wire
-        map_secs = time.perf_counter() - t_map
 
         keep = np.flatnonzero(w.gsup >= minsup)
         n = int(w.n_keep)
@@ -1257,9 +1306,10 @@ class Mirage:
             overflow=overflow, max_embeddings=M,
             rebalanced=w.rebalanced and n > 0, imbalance=w.imbalance,
             perm=w.perm if (w.rebalanced and n > 0) else None,
-            map_seconds=map_secs, escalations=escalations,
+            map_seconds=out.seconds, escalations=escalations,
             retried=retried, survivor_cap=S, spec_cands=spec_cands,
-            candgen_seconds=cand_secs, audit=int(w.audit))
+            candgen_seconds=cand_secs, audit=int(w.audit),
+            donated=donated, spec=spec)
 
     # ------------------------------------------------------------------
     def _level_legacy(self, meta_p, meta, C, pol, pmask, src, dst, emask,
@@ -1269,12 +1319,12 @@ class Mirage:
         with host round-trips between them (keep list, escalation loop,
         LPT detour).  Kept as differential oracle + benchmark baseline."""
         cfg = self.cfg
-        t_map = time.perf_counter()
-        gsup, verdict, emb_pp = map_reduce_supports(
-            self.mesh, meta_p, pol, pmask, src, dst, emask,
-            minsup=minsup, backend=cfg.backend, reduce=cfg.reduce)
-        faults.maybe_hang("dispatch", level, self._watchdog)
-        map_secs = time.perf_counter() - t_map
+        with tracing.Span("dispatch") as sp:
+            gsup, verdict, emb_pp = map_reduce_supports(
+                self.mesh, meta_p, pol, pmask, src, dst, emask,
+                minsup=minsup, backend=cfg.backend, reduce=cfg.reduce)
+            faults.maybe_hang("dispatch", level, self._watchdog)
+        map_secs = sp.seconds
 
         keep = np.flatnonzero(verdict[:C] != 0)
         if len(keep) == 0:
@@ -1313,58 +1363,64 @@ class Mirage:
         valve — keeps device supports == paper semantics)."""
         cfg = self.cfg
         escalations = 0
-        while True:
-            new_pol, new_pmask, overflow = map_materialize(
-                self.mesh, keep_meta, pol, pmask, src, dst, emask,
-                max_embeddings=M, out_width=out_width)
-            if (overflow == 0 or not cfg.escalate_on_overflow
-                    or M >= cfg.max_embeddings_limit):
-                return new_pol, new_pmask, overflow, M, escalations
-            M = min(M * 2, cfg.max_embeddings_limit)
-            escalations += 1
+        with tracing.Span("retry_materialize") as sp:
+            while True:
+                new_pol, new_pmask, overflow = map_materialize(
+                    self.mesh, keep_meta, pol, pmask, src, dst, emask,
+                    max_embeddings=M, out_width=out_width)
+                if (overflow == 0 or not cfg.escalate_on_overflow
+                        or M >= cfg.max_embeddings_limit):
+                    sp.set(escalations=escalations, M=M)
+                    return new_pol, new_pmask, overflow, M, escalations
+                M = min(M * 2, cfg.max_embeddings_limit)
+                escalations += 1
 
     def _device_put(self, pol, pmask, src, dst, emask):
         sharding = partition_sharding(self.mesh.mesh)
-        return tuple(jax.device_put(jnp.asarray(x), sharding)
-                     for x in (pol, pmask, src, dst, emask))
+        arrays = (pol, pmask, src, dst, emask)
+        with tracing.Span("upload", bytes=sum(x.nbytes for x in arrays)):
+            return tuple(jax.device_put(jnp.asarray(x), sharding)
+                         for x in arrays)
 
     def _save(self, root, level, levels, supports, pol, pmask, M, overflow,
               order):
-        # invert the cumulative rebalance permutation: checkpoints hold
-        # the OL store in canonical partition order (resume rebuilds the
-        # edge-OL store canonically and must stay row-aligned)
-        inv = np.empty_like(order)
-        inv[order] = np.arange(len(order))
-        max_edges = max(len(c) for l in levels for c in l)
-        pol_np, pmask_np = np.asarray(pol)[inv], np.asarray(pmask)[inv]
-        # checkpoints hold the CANONICAL store: bucket padding is
-        # stripped (pattern axis to the true survivor count, vertex axis
-        # to the widest real pattern) so a resume under different bucket
-        # floors — or none — re-pads into ITS family without inheriting
-        # the writer's.  Unbucketed stores pass through unchanged.
-        n_real = max(len(levels[-1]), 1)
-        pol_np, pmask_np = pol_np[:, :n_real], pmask_np[:, :n_real]
-        if self._buckets() is not None:
-            kw = 1 + max(max(i, j) for c in levels[-1]
-                         for (i, j, _a, _e, _b) in c)
-            pol_np = pol_np[..., :kw]
-        state = {
-            "levels": [[code_to_array(c, max_edges) for c in l]
-                       for l in levels],
-            "support_codes": [code_to_array(c, max_edges) for c in supports],
-            "support_vals": np.asarray(list(supports.values()), np.int64),
-            "pol": pol_np,
-            "pmask": pmask_np,
-            "max_embeddings": M,
-            "total_overflow": overflow,
-        }
-        # metadata the supervisor's partial-result cut branches on:
-        # "audited" marks steps written by an auditing run (the only
-        # levels a PartialResult may ever cut at), minsup + n_graphs
-        # parameterize the load-time re-audit
-        ckpt.save_step(root, level, state,
-                       metadata={"kind": "mirage-mining",
-                                 **self._ckpt_meta})
+        with tracing.Span("checkpoint", level=level):
+            # invert the cumulative rebalance permutation: checkpoints hold
+            # the OL store in canonical partition order (resume rebuilds the
+            # edge-OL store canonically and must stay row-aligned)
+            inv = np.empty_like(order)
+            inv[order] = np.arange(len(order))
+            max_edges = max(len(c) for l in levels for c in l)
+            pol_np, pmask_np = np.asarray(pol)[inv], np.asarray(pmask)[inv]
+            # checkpoints hold the CANONICAL store: bucket padding is
+            # stripped (pattern axis to the true survivor count, vertex axis
+            # to the widest real pattern) so a resume under different bucket
+            # floors — or none — re-pads into ITS family without inheriting
+            # the writer's.  Unbucketed stores pass through unchanged.
+            n_real = max(len(levels[-1]), 1)
+            pol_np, pmask_np = pol_np[:, :n_real], pmask_np[:, :n_real]
+            if self._buckets() is not None:
+                kw = 1 + max(max(i, j) for c in levels[-1]
+                             for (i, j, _a, _e, _b) in c)
+                pol_np = pol_np[..., :kw]
+            state = {
+                "levels": [[code_to_array(c, max_edges) for c in l]
+                           for l in levels],
+                "support_codes": [code_to_array(c, max_edges)
+                                  for c in supports],
+                "support_vals": np.asarray(list(supports.values()), np.int64),
+                "pol": pol_np,
+                "pmask": pmask_np,
+                "max_embeddings": M,
+                "total_overflow": overflow,
+            }
+            # metadata the supervisor's partial-result cut branches on:
+            # "audited" marks steps written by an auditing run (the only
+            # levels a PartialResult may ever cut at), minsup + n_graphs
+            # parameterize the load-time re-audit
+            ckpt.save_step(root, level, state,
+                           metadata={"kind": "mirage-mining",
+                                     **self._ckpt_meta})
 
 
 def _free_device_bytes(devices) -> Optional[int]:

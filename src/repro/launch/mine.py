@@ -13,6 +13,10 @@ watchdog deadline (deterministic hang detection for CI chaos runs);
 ``--audit-report PATH`` dumps the continuous invariant auditor's
 per-level report.  A malformed input database exits 2 with a one-line
 diagnosis (graph id + edge index) instead of a traceback.
+
+``--profile DIR`` records a ``jax.profiler`` trace of the run into DIR,
+with the compiled programs' HLO: the ``mirage:`` host spans and the
+``mirage/`` device scopes of ``runtime/tracing.py``.
 """
 from __future__ import annotations
 
@@ -115,6 +119,10 @@ def main() -> None:
                          "(device audit word + host spot checks)")
     ap.add_argument("--audit-report", default=None,
                     help="write the auditor's per-level report JSON here")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="record a jax.profiler trace of the run in DIR "
+                         "(TensorBoard's profile plugin or xprof open "
+                         "it), with the programs' HLO")
     args = ap.parse_args()
 
     import jax
@@ -167,6 +175,11 @@ def main() -> None:
         faults.install(schedule)
         print(f"[mine] chaos schedule: {schedule.describe()}")
 
+    if args.profile:
+        opts = jax.profiler.ProfileOptions()
+        # the optimized HLO names each device op's mirage/ scope
+        opts.enable_hlo_proto = True
+        jax.profiler.start_trace(args.profile, profiler_options=opts)
     sup = miner = None
     t0 = time.perf_counter()
     try:
@@ -199,6 +212,9 @@ def main() -> None:
         # (graph id + edge index) on stderr, no traceback
         print(f"[mine] invalid database: {exc}", file=sys.stderr)
         raise SystemExit(2)
+    finally:
+        if args.profile:
+            jax.profiler.stop_trace()
     dt = time.perf_counter() - t0
 
     if sup is not None and sup.events:

@@ -121,3 +121,37 @@ def test_level_program_compiles_for_v5e(topo):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES // 2, mem
+
+
+def test_named_scopes_leave_the_v5e_level_program_unchanged(topo,
+                                                            monkeypatch):
+    """The ``mirage/`` scopes change the op metadata of the compiled
+    level program and nothing else of it."""
+    from repro.core.level_step import _level_program
+    from repro.core.mapreduce import MiningMesh
+    from repro.runtime import jax_compat
+    from test_tracing import SCOPES, NoScope, code_only
+
+    mesh = MiningMesh(jax_compat.make_mesh((1,), ("w",),
+                                           devices=topo.devices[:1]))
+    rep = NamedSharding(mesh.mesh, mesh.replicated())
+    parts = NamedSharding(mesh.mesh, mesh.spec_parts())
+    cp, s, tile_c = 512, 128, 8
+    args = (_shape(rep, (), jnp.int32), _shape(rep, (P,), jnp.int32),
+            _shape(rep, (cp, 6), jnp.int32),
+            _shape(rep, (cp // tile_c, 2), jnp.int32),
+            _shape(rep, (cp,), jnp.int32), *_store_shapes(parts))
+
+    def compiled_text():
+        fn = _level_program.__wrapped__(
+            mesh, 1000, "fused", "reduce_scatter", M, s, True, 1.25, False,
+            K, True, True, 8 * 1250)
+        return fn.lower(*args).compile().as_text()
+
+    scoped = compiled_text()
+    monkeypatch.setattr(jax, "named_scope", lambda name: NoScope())
+    plain = compiled_text()
+    for scope in SCOPES:
+        assert f"mirage/{scope}/" in scoped, scope
+    assert "mirage/" not in plain
+    assert code_only(scoped) == code_only(plain)
