@@ -11,9 +11,10 @@ by adjoining one frequent edge:
 
 The adjoined edge's label triple must belong to the globally frequent
 edge alphabet (``F_1``), the Apriori prune.  Every candidate then passes
-the min-dfs-code canonicality test (`dfscode.is_canonical`): of all
-generation paths of a pattern exactly one survives, so the candidate
-space is duplicate-free (completeness + no recount).
+the min-dfs-code canonicality test (`dfscode.canonical_prefix`, the walk
+behind `dfscode.is_canonical`): of all generation paths of a pattern
+exactly one survives, so the candidate space is duplicate-free
+(completeness + no recount).
 
 Candidates are *metadata* (host-side, tiny).  Each carries the join recipe
 (`Extension`) the device layer executes against partition-local occurrence
@@ -29,7 +30,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .dfscode import (Code, Edge5, code_to_graph, is_canonical,
+from ..runtime import tracing
+from .dfscode import (Code, Edge5, canonical_prefix, code_to_graph,
                       rightmost_path, code_array_rightmost_path,
                       code_array_vertex_labels, min_dfs_canonical_array)
 
@@ -102,11 +104,15 @@ def generate_candidates(
 ) -> list[Candidate]:
     """All canonical size-(k+1) candidates from the frequent size-k set.
 
-    Host-side cost is O(|F_k| · RMP · alphabet) plus one canonicality check
-    per raw candidate — pattern-metadata scale, negligible next to
-    support counting (the device side).
+    Host-bound and on the critical path of the mining loop: the device
+    idles while it runs, unless the loop's speculation hides it in a
+    level program's shadow.  It costs O(|F_k| · RMP · alphabet) plus one
+    canonicality walk per raw candidate, seconds on a wide level.  The
+    counters ``canon_tested`` (raw candidates walked) and ``canon_early``
+    (those rejected before their last position) are added once per call.
     """
     out: list[Candidate] = []
+    tested = early = 0
     for pidx, code in enumerate(frequent):
         g = code_to_graph(code)
         rmp = rightmost_path(code)
@@ -125,7 +131,10 @@ def generate_candidates(
                     continue
                 edge: Edge5 = (rmv, w, int(vl[rmv]), e_lab, int(vl[w]))
                 child = code + (edge,)
-                if is_canonical(child):
+                tested += 1
+                at = canonical_prefix(child)
+                early += at < len(code)
+                if at == len(child):
                     out.append(Candidate(child, pidx,
                                          Extension(False, rmv, w,
                                                    (int(vl[rmv]), e_lab, int(vl[w])))))
@@ -135,10 +144,15 @@ def generate_candidates(
             for (e_lab, other) in alphabet.partners(int(vl[w])):
                 edge = (int(w), n_v, int(vl[w]), e_lab, other)
                 child = code + (edge,)
-                if is_canonical(child):
+                tested += 1
+                at = canonical_prefix(child)
+                early += at < len(code)
+                if at == len(child):
                     out.append(Candidate(child, pidx,
                                          Extension(True, int(w), n_v,
                                                    (int(vl[w]), e_lab, other))))
+    tracing.count("canon_tested", tested)
+    tracing.count("canon_early", early)
     return out
 
 

@@ -26,7 +26,7 @@ with ties broken by the label triple ``(l_i, l_e, l_j)``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Iterable, Optional, Sequence
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -46,6 +46,7 @@ __all__ = [
     "code_to_graph",
     "min_dfs_code",
     "is_canonical",
+    "canonical_prefix",
     "rightmost_path",
     "code_to_array",
     "array_to_code",
@@ -54,10 +55,6 @@ __all__ = [
     "code_array_rightmost_path",
     "min_dfs_canonical_array",
 ]
-
-
-def _is_forward(e: Edge5) -> bool:
-    return e[0] < e[1]
 
 
 def edge_lt(a: Edge5, b: Edge5) -> bool:
@@ -110,14 +107,6 @@ class _State:
     d2g: list[int]               # dfs id -> graph vid
     used: frozenset[int]         # used (undirected) edge indices
     rmp: tuple[int, ...]         # rightmost path, as dfs ids root..rightmost
-
-
-def _edge_index(g: Graph) -> dict[tuple[int, int], list[int]]:
-    idx: dict[tuple[int, int], list[int]] = {}
-    for k, (u, v) in enumerate(map(tuple, g.edges)):
-        idx.setdefault((u, v), []).append(k)
-        idx.setdefault((v, u), []).append(k)
-    return idx
 
 
 def min_dfs_code(
@@ -225,13 +214,102 @@ def _ext_forward(st: _State, eidx: int, nbr_g: int, from_dfs: int) -> _State:
 
 
 def is_canonical(code: Code) -> bool:
-    """True iff ``code`` equals the min-dfs-code of its own pattern graph.
+    """True iff ``code`` equals the min-dfs-code of its own pattern graph,
+    i.e. ``min_dfs_code(code_to_graph(code)) == code``.
 
     This is exactly the mapper's isomorphism_checking() (paper Fig. 7
     line 3): of all generation paths of a pattern, only the one matching
     the min-dfs-code survives.
     """
-    return min_dfs_code(code_to_graph(code), bound=code) == code
+    return canonical_prefix(code) == len(code)
+
+
+def canonical_prefix(code: Code) -> int:
+    """How many leading edges of ``code`` the min-dfs-code agrees with:
+    ``len(code)`` iff ``code`` is canonical, else the position at which a
+    smaller code (or none at all) is found.
+
+    The walk of `min_dfs_code`, checked against ``code`` as it goes: the
+    pattern graph is read straight off the code's tuples (vertex ids are
+    the code's own dfs ids), and at each position every extension of
+    every state is compared with ``code[pos]`` as it is found.  A smaller
+    one ends the walk (any partial DFS traversal can be completed, so a
+    smaller prefix proves a smaller code); an equal one carries its
+    state forward; a larger one is dropped without building a state.
+    All states realize the same prefix, so the rightmost path, and how
+    each extension's ``(i, j)`` ranks against ``code[pos]``, are shared.
+    """
+    vl: dict[int, int] = {}
+    adj: dict[int, list[tuple[int, int, int]]] = {}  # v -> [(nbr, el, k)]
+    for k, (i, j, li, le, lj) in enumerate(code):
+        vl[i] = li
+        vl[j] = lj
+        adj.setdefault(i, []).append((j, le, k))
+        adj.setdefault(j, []).append((i, le, k))
+
+    # a state: (d2g, used) — dfs id -> pattern vertex, used-edge bitmask
+    head = code[0]
+    if head[:2] != (0, 1):
+        return 0
+    want = head[2:]
+    states: list[tuple[tuple[int, ...], int]] = []
+    for k, (i, j, _, le, _) in enumerate(code):
+        for a, b in ((i, j), (j, i)):
+            t = (vl[a], le, vl[b])
+            if t < want:
+                return 0
+            if t == want:
+                states.append(((a, b), 1 << k))
+    if not states:
+        return 0
+
+    rmp = [0, 1]                 # rightmost path, dfs ids root..rightmost
+    for pos in range(1, len(code)):
+        target = code[pos]
+        want = target[2:]
+        n_d = len(states[0][0])  # dfs ids so far; the next new one
+        rm = rmp[-1]
+        # where each possible (i, j) falls against code[pos] in
+        # `edge_lt`'s order, whatever its labels: the sort key is the
+        # tuple form of `edge_struct_key`.  Keys past it are left out;
+        # the flag is True for a key before it, False for its own.
+        ti, tj = target[0], target[1]
+        tkey = (2 * tj, -ti) if ti < tj else (2 * ti + 1, tj)
+        bk, fk = 2 * rm + 1, 2 * n_d
+        back = {jd: (bk, jd) < tkey for jd in rmp[:-1] if (bk, jd) <= tkey}
+        fwd = [(wd, (fk, -wd) < tkey) for wd in reversed(rmp)
+               if (fk, -wd) <= tkey]
+        nexts: list[tuple[tuple[int, ...], int]] = []
+        for d2g, used in states:
+            if back:
+                rm_g = d2g[rm]
+                for nbr, el, k in adj[rm_g]:
+                    if used >> k & 1 or nbr not in d2g:
+                        continue
+                    before = back.get(d2g.index(nbr))
+                    if before is None:
+                        continue
+                    t = (vl[rm_g], el, vl[nbr])
+                    if before or t < want:
+                        return pos
+                    if t == want:
+                        nexts.append((d2g, used | 1 << k))
+            for wd, before in fwd:
+                wg = d2g[wd]
+                for nbr, el, k in adj[wg]:
+                    if nbr in d2g:
+                        continue
+                    t = (vl[wg], el, vl[nbr])
+                    if before or t < want:
+                        return pos
+                    if t == want:
+                        nexts.append((d2g + (nbr,), used | 1 << k))
+        if not nexts:
+            return pos
+        states = nexts
+        if target[0] < target[1]:           # forward: the path is cut
+            rmp = rmp[:rmp.index(target[0]) + 1] + [target[1]]
+    return len(code)
 
 
 def rightmost_path(code: Code) -> tuple[int, ...]:

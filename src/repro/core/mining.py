@@ -624,6 +624,7 @@ class Mirage:
                     wd.check_run(level=k + 1)
                 if cands is None:
                     with tracing.Span("candgen",
+                                      counts=tracing.CANON_COUNTS,
                                       parents=len(levels[-1])) as cg:
                         fresh = False
                         if spec is not None:
@@ -1249,7 +1250,8 @@ class Mirage:
             spec = "skipped"
             if est <= window:
                 spec = "taken"
-                with tracing.Span("candgen_spec", est_s=est,
+                with tracing.Span("candgen_spec",
+                                  counts=tracing.CANON_COUNTS, est_s=est,
                                   window_s=window) as sp:
                     spec_cands = generate_candidates(
                         [c.code for c in cands], alphabet)

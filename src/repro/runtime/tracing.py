@@ -13,7 +13,8 @@ span's reading and its event in a trace agree.
 The phases (args in brackets; "levels"/"compiles"/... at close):
 
   fit               the whole fit [n_graphs, minsup, pipeline; levels,
-                    compiles, wire_fetches, gc_gen2, gc_s]
+                    compiles, wire_fetches, gc_gen2, gc_s, canon_tested,
+                    canon_early]
   partition         ``make_partitions`` [n_parts]
   edge_ol_build     the per-partition edge OLs, padded and stacked [F]
   level1            the level-1 OLs and supports [codes]
@@ -21,9 +22,10 @@ The phases (args in brackets; "levels"/"compiles"/... at close):
   level             one level of the mining loop [level, candidates, Cp,
                     S, M, donated, retried, escalations, spec, compiles]
   candgen           the loop-head candidates: generated, or narrowed from
-                    the previous level's speculation [parents, candidates]
+                    the previous level's speculation [parents, candidates;
+                    tested, early]
   candgen_spec      the speculative candgen in the level program's
-                    shadow [est_s, window_s]
+                    shadow [est_s, window_s; tested, early]
   candidate_meta    candidate metadata, its padding and the parent
                     supports for the device audit
   schedule          the host side of a level dispatch: the fused kernel's
@@ -46,6 +48,11 @@ one's change over the span as an arg:
   wire_fetches  level-wire transfers, re-fetches after a checksum
                 mismatch included
   gc_gen2       generation-2 collections; gc_s their seconds
+  canon_tested  raw candidates ``generate_candidates`` put through the
+                canonicality walk (span arg ``tested``; 0 when the
+                candidates were narrowed from a speculation)
+  canon_early   those of them the walk rejected before their last
+                position, on a smaller prefix (span arg ``early``)
 
 Device work is named by ``jax.named_scope`` inside the programs
 (``mirage/support_kernel``, ``mirage/reduce``, ``mirage/compact``,
@@ -61,13 +68,17 @@ from typing import Optional
 
 import jax
 
-__all__ = ["PREFIX", "FIT_COUNTS", "Span", "count"]
+__all__ = ["PREFIX", "FIT_COUNTS", "CANON_COUNTS", "Span", "count"]
 
 PREFIX = "mirage:"
 
 _LOWERING = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
-_totals = {"compiles": 0, "wire_fetches": 0, "gc_gen2": 0, "gc_s": 0.0}
+_totals = {"compiles": 0, "wire_fetches": 0, "gc_gen2": 0, "gc_s": 0.0,
+           "canon_tested": 0, "canon_early": 0}
+
+#: the counters the candgen spans report, as args ``tested`` and ``early``
+CANON_COUNTS = {"tested": "canon_tested", "early": "canon_early"}
 
 #: the counters the ``fit`` span reports, under their own names
 FIT_COUNTS = {name: name for name in _totals}

@@ -166,6 +166,44 @@ def test_fit_span_counts_gc_and_wire_fetches(warm_fit):
     assert all("collected" in e[3] for e in gcs)
 
 
+@pytest.fixture(scope="module")
+def fresh_fit(tmp_path_factory):
+    """A warm fit that generates every level's candidates at the loop
+    head (no speculation), recorded by the profiler."""
+    graphs = _db()
+    miner = _miner(str(tmp_path_factory.mktemp("ckpt")),
+                   overlap_candgen=False)
+    miner.fit(graphs)
+    return _traced(lambda: miner.fit(graphs))
+
+
+@pytest.mark.parametrize("fit", ["warm_fit", "fresh_fit"])
+def test_candgen_spans_count_the_canonicality_walk(fit, request):
+    """``tested`` and ``early`` on every candgen span: a fresh generation
+    tests at least the candidates it keeps, a narrowed one tests none,
+    and the fit's counters are the sum over the spans."""
+    res, events = request.getfixturevalue(fit)
+    gen = [e[3] for e in events if e[0] == "candgen"]
+    spec = [e[3] for e in events if e[0] == "candgen_spec"]
+    assert len(gen) == len(res.stats)
+    for a in gen + spec:
+        assert 0 <= a["early"] <= a["tested"]
+    if fit == "warm_fit":
+        # the first level generates; the others narrow the speculation
+        assert gen[0]["tested"] >= gen[0]["candidates"] > 0
+        assert all(a["tested"] == 0 for a in gen[1:])
+        assert len(spec) == len(res.stats)
+        assert all(a["tested"] > 0 for a in spec)
+    else:
+        assert spec == []
+        assert all(a["tested"] >= a["candidates"] for a in gen)
+        assert all(a["tested"] > 0 for a in gen)
+    total = next(e[3] for e in events if e[0] == "fit")
+    assert total["canon_tested"] == sum(a["tested"] for a in gen + spec)
+    assert total["canon_early"] == sum(a["early"] for a in gen + spec)
+    assert total["canon_early"] > 0
+
+
 def test_compiles_counted_on_a_cold_fit_and_none_on_a_warm_one(tmp_path):
     graphs = _db()
     # a rebalance threshold no other test uses keys a fresh level
