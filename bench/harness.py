@@ -8,7 +8,10 @@ compiled or loaded from the persistent cache then), and measures:
   the end of the fit during which ``seconds`` have elapsed.  The
   end-to-end metrics come from it.
 * ``--trace 1``: one more fit under the profiler, with the program's
-  host phases in spans.  The per-layer metrics come from it.
+  host phases in spans.  The per-layer metrics come from it, from the
+  benchmark's own spans and the chip's ops (``bench/trace.py``) and
+  from the program's own spans, counters and device scopes
+  (``bench/phases.py``, loaded once the fit is over).
 
 Once the measuring is done and the device memory has been read, the
 plain reference mines the same DB, and every fit's frequent set and
@@ -27,13 +30,16 @@ import tempfile
 import time
 import traceback
 from pathlib import Path
-from typing import Callable, Optional
+from typing import TYPE_CHECKING, Callable, Optional
 
 from . import cells, roofline, system
 from . import trace as tracing
 from .gen.common import reorder
 from .peaks import peaks
 from .ref import miner as refminer
+
+if TYPE_CHECKING:
+    from .phases import Phases
 
 LOWERING = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -64,6 +70,7 @@ class RunInputs:
     shapes: list = dataclasses.field(default_factory=list)
     trace: Optional[tracing.Trace] = None
     spanned: frozenset = frozenset()  # host spans put in place (traced)
+    phases: Optional[Phases] = None  # the program's spans and scopes
 
 
 @contextlib.contextmanager
@@ -223,9 +230,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         log(f"warm-up fit {time.perf_counter() - t:.3f}s, "
             f"{warm['lowered']} lowered, {warm['compiled']} compiled")
     setup_s = time.perf_counter() - t0
+    log(f"setup: {setup_s:.3f}s")
 
     answers, stats, walls = [], [], []
-    tr, breakdown, spanned = None, None, frozenset()
+    tr, ph, breakdown, spanned = None, None, None, frozenset()
 
     def one_fit() -> bool:
         t = time.perf_counter()
@@ -266,6 +274,14 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
             tr = tracing.load(xplane)
             if dump is not None:
                 _dump(dump, xplane, tr)
+            # imported only now: its proto parser takes seconds to load,
+            # which must land in neither the set-up nor the window
+            t = time.perf_counter()
+            from . import phases
+
+            ph = phases.load(xplane)
+            log(f"program phases: {len(ph.spans)} spans, scoped "
+                f"{ph.scoped}, read in {time.perf_counter() - t:.2f}s")
     log(f"window: {len(walls)} fit(s) in {window_s:.3f}s, fits "
         f"{[round(w, 3) for w in walls]}, {win['lowered']} lowered and "
         f"{win['compiled']} compiled inside the window")
@@ -285,7 +301,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         setup_s=setup_s, window_s=window_s, fits=len(walls),
         peak_bytes=peak, stats=stats, fit_walls=walls,
         devices=[d.id for d in devices], peaks=pk, trace=tr,
-        spanned=spanned, shapes=roofline.level_shapes(
+        spanned=spanned, phases=ph, shapes=roofline.level_shapes(
             ref, n, int(config["n_partitions"]),
             int(config.get("miner", {}).get("max_embeddings", 32))))
     if trace:

@@ -174,6 +174,14 @@ def gc_s(ph: Phases) -> Optional[float]:
     return None if fit is None else float(fit[3].get("gc_s", 0.0))
 
 
+def canon_tested(ph: Phases) -> Optional[int]:
+    """Raw candidates the fit put through candgen's canonicality walk
+    (its span's ``canon_tested`` arg); None where the program does not
+    count them."""
+    fit = _fit(ph)
+    return None if fit is None else fit[3].get("canon_tested")
+
+
 def scope_ns(ph: Phases, chips: Iterable[int], scope: str
              ) -> Optional[float]:
     """Device busy time of the ops under ``mirage/<scope>``, mean over
@@ -273,6 +281,7 @@ def summary(path: str | Path, chip: int = 0) -> dict:
         "prep_s": prep_s(ph), "candgen_s": candgen_s(ph),
         "spec_candgen_s": spec_candgen_s(ph),
         "wire_wait_s": wire_wait_s(ph), "gc_s": gc_s(ph),
+        "canon_tested": canon_tested(ph),
         "materialize_ms": materialize_ms(ph, [chip]),
         "scope_ms": {s: scope_ns(ph, [chip], s) / 1e6 for s in scopes},
         "levels": [sp[3] for sp in ph.spans if sp[0] == "level"],

@@ -2,12 +2,15 @@
 in the timed path, for the tests.
 
     python bench/tests/drive.py CELL N_GRAPHS [--trace] [--fault NAME]
+                                [--watch-imports]
     python bench/tests/drive.py CELL N_GRAPHS --control
 
 The harness's look for a chip is skipped (``require_tpu=False``) and the
 DB is cut to ``N_GRAPHS``; everything else runs as in a benchmark run.
 A four-chip cell gets four virtual CPU devices.  Prints the result
-line, or with ``--control`` one line per control reading.
+line, or with ``--control`` one line per control reading.  With
+``--watch-imports`` every message of the harness, and the end of every
+fit, is logged with whether ``bench.phases`` was imported by then.
 """
 import argparse
 import dataclasses
@@ -72,6 +75,24 @@ FAULTS = {f.__name__: f for f in (state_unchanged, half_batch,
                                   answer_altered)}
 
 
+def imported() -> str:
+    return f"[phases imported: {'bench.phases' in sys.modules}]"
+
+
+def watch_fits(log):
+    """Log the end of every ``Mirage.fit`` with :func:`imported`."""
+    from repro.core import mining
+
+    orig = mining.Mirage.fit
+
+    def fit(self, *args, **kwargs):
+        out = orig(self, *args, **kwargs)
+        log("fit ended")
+        return out
+
+    mining.Mirage.fit = fit
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("cell")
@@ -79,6 +100,7 @@ def main(argv=None) -> int:
     ap.add_argument("--trace", action="store_true")
     ap.add_argument("--fault", choices=sorted(FAULTS))
     ap.add_argument("--control", action="store_true")
+    ap.add_argument("--watch-imports", action="store_true")
     args = ap.parse_args(argv)
     t0 = time.perf_counter()
     os.environ["JAX_PLATFORMS"] = "cpu"
@@ -89,6 +111,9 @@ def main(argv=None) -> int:
     if args.fault:
         FAULTS[args.fault]()
     log = (lambda msg: print(f"[drive] {msg}", file=sys.stderr, flush=True))
+    if args.watch_imports:
+        log = (lambda msg, say=log: say(f"{msg} {imported()}"))
+        watch_fits(log)
     if args.control:
         for r in control.readings(args.cell, [1], [2, 3], root=ROOT,
                                   require_tpu=False, n_graphs=args.n_graphs):

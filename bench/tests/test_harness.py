@@ -10,7 +10,7 @@ from pathlib import Path
 
 import pytest
 
-from _sub import drive
+from _sub import drive, drive_logged
 from bench import cells
 
 ROOT = Path(__file__).resolve().parents[2]
@@ -88,6 +88,37 @@ def test_traced_run_on_cpu(cell):
     assert m["level_wait_s"]["value"] > 0 and m["host_s"]["value"] > 0
     assert {"busy_s", "window_s"} <= set(res["device"])
     assert list(res)[-1] == "checks"
+
+
+@pytest.mark.parametrize("traced", [True, False], ids=["trace1", "trace0"])
+def test_program_phases_are_read_only_after_the_measured_fit(traced):
+    """``setup_s`` is read, and every fit (the warm-up and the measured
+    ones) ends, before ``bench/phases.py`` is imported; a traced run
+    then attaches the program's spans, and its metrics read them."""
+    (res,), err = drive_logged("aids-ms5", 64, "--watch-imports",
+                               *(["--trace"] if traced else []))
+    lines = [ln for ln in err.splitlines() if ln.startswith("[drive] ")]
+    setup = [ln for ln in lines if ln.startswith("[drive] setup: ")]
+    fits = [i for i, ln in enumerate(lines) if "fit ended" in ln]
+    read = [i for i, ln in enumerate(lines)
+            if ln.startswith("[drive] program phases: ")]
+    assert len(setup) == 1 and setup[0].endswith("[phases imported: False]")
+    assert len(fits) >= 2
+    assert all(lines[i].endswith("[phases imported: False]") for i in fits)
+    assert res["correct"] is True
+    m = res["metrics"]
+    if not traced:
+        assert read == [] and "phases imported: True" not in err
+        assert set(m) == {"fit_s", "peak_hbm_gb", "setup_s"}
+        return
+    assert len(read) == 1 and read[0] > fits[-1]
+    assert lines[read[0]].endswith("[phases imported: True]")
+    # the CPU trace has the spans and counters; scopes and the kernel's
+    # ops only a chip's trace has
+    assert {"prep_s", "candgen_s", "wire_wait_s", "gc_s",
+            "canon_tested"} <= set(m)
+    assert m["canon_tested"]["value"] > 0 and m["prep_s"]["value"] > 0
+    assert not {"materialize_ms", "kernel_least_roofline"} & set(m)
 
 
 @pytest.mark.parametrize("cell", ["aids-ms5"])
