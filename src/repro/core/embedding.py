@@ -44,6 +44,8 @@ __all__ = [
     "build_edge_ol", "level1_ol", "candidate_meta",
     "join_valid", "local_supports_ref", "support_bits_ref",
     "materialize_rows", "materialize_prefix",
+    "NOKEY", "build_adjacency", "empty_spill", "spill_support_rows",
+    "spill_child_counts", "spill_write",
 ]
 
 PAD = -1
@@ -353,3 +355,334 @@ def materialize_prefix(cmeta, n_fill, pol, pmask, src, dst, emask, *,
             jnp.zeros((PP, n_slots, G, max_embeddings), bool),
             jnp.zeros((), jnp.int32))
     return jax.lax.fori_loop(0, n_fill, one, init)
+
+
+# ---------------------------------------------------------------------------
+# Spill layout — an exact store past the dense M (embedding_store="spill")
+# ---------------------------------------------------------------------------
+#
+# Under the spill store a (pattern, graph) pair lives in ONE of two places,
+# whole:
+#
+#   dense  ol (P, G, M, W) / mask (P, G, M): a pair with at most M
+#          embeddings, whose parent pair was dense too — the layout above;
+#   spill  rows (R, W) int32 / key (R,) int32: every embedding of a pair
+#          with more than M, and of every child of a spilled pair, one row
+#          each, keyed ``pattern * G + graph`` and sorted by key; unused
+#          rows carry ``NOKEY`` and sort last.  R is bucketed.
+#
+# A pair's slots in the dense store stay masked while it spills, so the
+# dense join and the spill join see disjoint embeddings: a candidate's
+# local support is the dense kernel's count plus the graphs in which a
+# spilled pair of its parent matches.  The spill join reads the edge
+# occurrences regrouped by their source vertex (``build_adjacency``):
+# each row looks up only its own vertices' neighbours (D of them) where
+# the dense join scans a triple's F occurrences per graph.
+
+NOKEY = 1 << 30
+
+
+def build_adjacency(src: np.ndarray, dst: np.ndarray, emask: np.ndarray
+                    ) -> tuple[np.ndarray, np.ndarray]:
+    """The edge occurrences (NP, T, G, F) regrouped by source vertex:
+    ``nbr`` (NP, G, V, D) the destination vertex and ``code`` (NP, G, V,
+    D) the triple index of each occurrence leaving vertex v of graph g,
+    -1 padded.  Per source vertex the entries keep the edge OL's (triple,
+    occurrence) order.  The spill join reads a backward edge as THE code
+    of the occurrence between two vertices, so a graph may hold at most
+    one occurrence per ordered vertex pair: a ``ValueError`` otherwise."""
+    n, t, g, f = np.nonzero(emask)
+    u = src[n, t, g, f].astype(np.int64)
+    v = dst[n, t, g, f].astype(np.int64)
+    NP, _, G, _ = src.shape
+    V = int(max(u.max(), v.max())) + 1 if u.size else 1
+    order = np.lexsort((f, t, u, g, n))
+    n, t, g, u, v = n[order], t[order], g[order], u[order], v[order]
+    group = (n * G + g) * V + u
+    pair = group * V + v
+    if np.unique(pair).size != pair.size:
+        raise ValueError("the spill store needs graphs with at most one "
+                         "edge occurrence per ordered vertex pair")
+    idx = np.arange(u.size)
+    new = np.r_[True, group[1:] != group[:-1]] if u.size else idx > 0
+    slot = idx - np.maximum.accumulate(np.where(new, idx, 0))
+    D = int(slot.max()) + 1 if u.size else 1
+    nbr = np.full((NP, G, V, D), PAD, np.int32)
+    code = np.full((NP, G, V, D), PAD, np.int32)
+    nbr[n, g, u, slot] = v
+    code[n, g, u, slot] = t
+    return nbr, code
+
+
+def empty_spill(n_parts: int, width: int, rows: int
+                ) -> tuple[np.ndarray, np.ndarray]:
+    """A spill table with no rows: (NP, R, W) PAD rows, (NP, R) NOKEY."""
+    return (np.full((n_parts, rows, width), PAD, np.int32),
+            np.full((n_parts, rows), NOKEY, np.int32))
+
+
+def _ext_one(rows, g, cand, nbr, code):
+    """Extensions of one candidate per row: ``rows`` (N, W) embeddings of
+    graphs ``g`` (N,), ``cand`` (N, 5) or (5,) candidate rows.  Returns
+    ``(ok (N, D), nb (N, D))``: neighbour d of the stub vertex completes
+    a child embedding (a new vertex for a forward edge, the ``to``
+    vertex for a backward one) — ``join_valid``'s test, per neighbour."""
+    cand = jnp.broadcast_to(cand, rows.shape[:1] + (5,))
+    stub, to, fwd, t = cand[:, 1], cand[:, 2], cand[:, 3], cand[:, 4]
+    u = jnp.take_along_axis(rows, stub[:, None], axis=1)[:, 0]
+    uc = jnp.maximum(u, 0)
+    nb = nbr[g, uc]                                            # (N, D)
+    cd = jnp.where((u >= 0)[:, None], code[g, uc], PAD)
+    fresh = ~(nb[:, :, None] == rows[:, None, :]).any(-1)
+    tov = jnp.take_along_axis(rows, to[:, None], axis=1)
+    ok = (cd == t[:, None]) & jnp.where(fwd[:, None].astype(bool), fresh,
+                                        nb == tov)
+    return ok, nb
+
+
+def _n_children(ok, fwd):
+    """Child embeddings per row: every match of a forward edge, at most
+    one of a backward edge (``materialize_rows`` keeps its first f)."""
+    return jnp.where(fwd.astype(bool), ok.sum(-1, dtype=jnp.int32),
+                     ok.any(-1).astype(jnp.int32))
+
+
+def _row_tables(rows, g, nbr, code, n_triples: int):
+    """Per-row extension tables, shared by every candidate of the row's
+    pattern: ``fcnt`` (N, W·T) forward children from vertex slot i along
+    triple t (flat ``i·T + t``) and ``edge`` (N, W·W) the triple of the
+    occurrence from slot i to slot j, -1 if none (flat ``i·W + j``)."""
+    N, W = rows.shape
+    uc = jnp.maximum(rows, 0)
+    nb = nbr[g[:, None], uc]                                   # (N, W, D)
+    cd = jnp.where((rows >= 0)[:, :, None], code[g[:, None], uc], PAD)
+    fresh = ~(nb[..., None] == rows[:, None, None, :]).any(-1)
+    fcnt = ((cd[..., None] == jnp.arange(n_triples)) & fresh[..., None]
+            ).sum(2, dtype=jnp.int32)                           # (N, W, T)
+    hit = (nb[..., None] == rows[:, None, None, :]) & (rows >= 0)[:, None,
+                                                                  None, :]
+    edge = jnp.where(hit, cd[..., None], PAD).max(2)           # (N, W, W)
+    return fcnt.reshape(N, W * n_triples), edge.reshape(N, W * W)
+
+
+def _pick(fcnt, edge, cm, n_triples: int, width: int):
+    """Child counts in each row of the candidates ``cm`` (K, 5), read
+    from ``_row_tables`` through one-hot selector matmuls (no per-element
+    gather): (N, K) int32.  Table values are small integers, exact in
+    the f32 products at the highest precision."""
+    stub, to, fwd, t = cm[:, 1], cm[:, 2], cm[:, 3], cm[:, 4]
+
+    def select(table, cell):
+        sel = jnp.arange(table.shape[1])[:, None] == cell[None, :]
+        return jnp.dot(table.astype(jnp.float32), sel.astype(jnp.float32),
+                       precision=jax.lax.Precision.HIGHEST)
+
+    f = select(fcnt, stub * n_triples + t)
+    b = select(edge, stub * width + to) == t[None, :].astype(jnp.float32)
+    return jnp.where(fwd.astype(bool)[None, :], f.astype(jnp.int32),
+                     b.astype(jnp.int32))
+
+
+def _row_chunk(rows: int, width: int, n_triples: int) -> int:
+    """Rows per step of a row loop: a power of two dividing ``rows``
+    that keeps the (chunk, W, W, max(W, T)) intermediates near 2^26."""
+    c = 1 << max(6, (2 ** 26 // max(1, width * width * max(width,
+                                                        n_triples)))
+                 .bit_length() - 1)
+    while rows % c:
+        c //= 2
+    return max(1, min(c, rows))
+
+
+def spill_support_rows(meta, by_parent, cpos, rows, key, nbr, code, *,
+                       n_graphs: int, n_triples: int):
+    """Spill half of one partition's local support: per candidate, the
+    graphs in which some spilled pair of its parent holds an embedding
+    the candidate extends.  ``meta`` (C, 5) canonical candidate rows,
+    ``by_parent`` (P, Kc) the candidates of each parent (-1 padded),
+    ``cpos`` (C,) each candidate's flat index ``parent·Kc + j`` there;
+    ``rows``/``key`` the partition's spill table.  Returns (C,) int32.
+
+    A parent's rows are contiguous (sorted keys), so each parent sweeps
+    only its own rows, in chunks, against its own Kc candidates; each
+    chunk's hits are summed per graph by a one-hot matmul, and a
+    candidate's count is the graphs with a hit.  A parent with no
+    spilled rows costs no sweep."""
+    R, W = rows.shape
+    P, Kc = by_parent.shape
+    G, T = n_graphs, n_triples
+    rc = _row_chunk(R, W, T)
+    starts = jnp.searchsorted(key, jnp.arange(P + 1) * G).astype(jnp.int32)
+
+    def parent(p):
+        lo, hi = starts[p], starts[p + 1]
+        c = by_parent[p]
+        cm = jnp.take(meta, jnp.maximum(c, 0), axis=0)         # (Kc, 5)
+
+        def sweep(i, acc):
+            first = lo + i * rc
+            at = jnp.minimum(first, R - rc)
+            r = jax.lax.dynamic_slice_in_dim(rows, at, rc)
+            g = jax.lax.dynamic_slice_in_dim(key, at, rc) % G
+            idx = at + jnp.arange(rc)
+            valid = (idx >= first) & (idx < hi)
+            fcnt, edge = _row_tables(r, g, nbr, code, T)
+            hit = (_pick(fcnt, edge, cm, T, W) > 0) & (c >= 0)[None, :]
+            in_g = (g[:, None] == jnp.arange(G)[None, :]) & valid[:, None]
+            return acc + jnp.dot(in_g.T.astype(jnp.float32),
+                                 hit.astype(jnp.float32),
+                                 precision=jax.lax.Precision.HIGHEST)
+
+        acc = jax.lax.fori_loop(0, (hi - lo + rc - 1) // rc, sweep,
+                                jnp.zeros((G, Kc), jnp.float32))
+        return (acc > 0).sum(0, dtype=jnp.int32)
+
+    counts = jax.lax.map(parent, jnp.arange(P))                 # (P, Kc)
+    flat = jnp.concatenate([counts.reshape(-1),
+                            jnp.zeros((1,), jnp.int32)])
+    return jnp.take(flat, jnp.where(cpos < 0, P * Kc, cpos))
+
+
+def _last_le(get, lo, hi, target, steps: int):
+    """Largest index in [lo, hi) whose ``get`` is <= ``target``, for a
+    non-decreasing ``get`` with ``get(lo) <= target``: a binary search
+    of ``steps`` halvings, vectorized over the queries."""
+    def body(_, lh):
+        lo, hi = lh
+        mid = (lo + hi) // 2
+        le = get(mid) <= target
+        go = hi - lo > 1
+        return (jnp.where(go & le, mid, lo), jnp.where(go & ~le, mid, hi))
+
+    return jax.lax.fori_loop(0, steps, body, (lo, hi))[0]
+
+
+def spill_child_counts(cmeta, n_fill, pol, pmask, rows, key, nbr, code, *,
+                       n_triples: int, max_embeddings: int):
+    """Count pass of one partition's spill materialization.
+
+    For survivor slot s (``cmeta`` (S, 5), the first ``n_fill`` real)
+    and graph g: ``cdx`` (S, G, M+1) the exclusive prefix over the dense
+    parent rows of their child counts, ``csx`` (R+1, S) the same over the
+    spill rows, ``bounds`` (P, G+1) where each parent pair's rows start
+    in the spill table, and ``spilled`` (S, G) whether the child pair
+    goes to the spill (more than M children, or a spilled parent).
+    Returns those and ``(rows, pairs)`` the child spill table's size."""
+    P, G, Mp, W = pol.shape
+    S = cmeta.shape[0]
+    R = rows.shape[0]
+    valid_s = jnp.arange(S) < n_fill
+
+    def dense(xs):
+        cand, live = xs
+        r = jnp.take(pol, cand[0], axis=0).reshape(G * Mp, W)
+        m = jnp.take(pmask, cand[0], axis=0).reshape(G * Mp)
+        ok, _ = _ext_one(r, jnp.repeat(jnp.arange(G), Mp), cand, nbr, code)
+        n = jnp.where(m, _n_children(ok, cand[3]), 0).reshape(G, Mp)
+        return jnp.where(live, n, 0)
+
+    cd = jax.lax.map(
+        lambda xs: jax.lax.cond(xs[1], dense,
+                                lambda _: jnp.zeros((G, Mp), jnp.int32), xs),
+        (cmeta, valid_s))
+    cdx = jnp.concatenate([jnp.zeros((S, G, 1), jnp.int32),
+                           jnp.cumsum(cd, axis=-1)], axis=-1)
+    rc = _row_chunk(R, W, n_triples)
+
+    def sweep(xs):
+        r, k = xs
+        fcnt, edge = _row_tables(r, k % G, nbr, code, n_triples)
+        own = (cmeta[None, :, 0] == (k // G)[:, None]) & valid_s[None]
+        return jnp.where(own & (k < NOKEY)[:, None],
+                         _pick(fcnt, edge, cmeta, n_triples, W), 0)
+
+    def chunk(xs):
+        return jax.lax.cond(xs[1][0] < NOKEY, sweep,
+                            lambda _: jnp.zeros((rc, S), jnp.int32), xs)
+
+    cs = jax.lax.map(chunk, (rows.reshape(R // rc, rc, W),
+                             key.reshape(R // rc, rc))).reshape(R, S)
+    csx = jnp.concatenate([jnp.zeros((1, S), jnp.int32),
+                           jnp.cumsum(cs, axis=0)], axis=0)
+    bounds = jnp.searchsorted(
+        key, (jnp.arange(P)[:, None] * G + jnp.arange(G + 1)[None, :]),
+        side="left").astype(jnp.int32)                           # (P, G+1)
+    pb = jnp.take(bounds, cmeta[:, 0], axis=0)                  # (S, G+1)
+    col = jnp.arange(S)[:, None]
+    n_sp = csx[pb[:, 1:], col] - csx[pb[:, :-1], col]           # (S, G)
+    n_d = cdx[..., -1]
+    spilled = valid_s[:, None] & ((n_sp > 0) | (n_d > max_embeddings))
+    n = jnp.where(spilled, n_d + n_sp, 0)
+    return (cdx, csx, bounds, spilled,
+            n.sum(dtype=jnp.int32), spilled.sum(dtype=jnp.int32))
+
+
+def spill_write(cmeta, cdx, csx, bounds, spilled, pol, rows, key, nbr,
+                code, *, out_rows: int, out_width: int):
+    """Write pass: the child spill table of one partition, ``out_rows``
+    rows of width ``out_width`` sorted by (survivor slot, graph), from
+    the count pass's outputs.  Output row o is the k-th child of its
+    pair: found by a binary search over the pair's parent rows (dense or
+    spilled) in their child-count prefix, then the (k - before)-th
+    extension of that parent row."""
+    P, G, Mp, W = pol.shape
+    S = cmeta.shape[0]
+    R = rows.shape[0]
+    n_d = cdx[..., -1]
+    pb = jnp.take(bounds, cmeta[:, 0], axis=0)
+    col = jnp.arange(S)[:, None]
+    n_sp = csx[pb[:, 1:], col] - csx[pb[:, :-1], col]
+    per = jnp.where(spilled, n_d + n_sp, 0).reshape(-1)         # (S·G,)
+    incl = jnp.cumsum(per)
+    total = incl[-1]
+    qc = min(out_rows, 16384)
+    pol_flat = pol.reshape(P * G * Mp, W)
+    csx_flat = csx.reshape(-1)
+    cdx_flat = cdx.reshape(-1)
+
+    def chunk(o):
+        return jax.lax.cond(
+            o[0] < total, write, lambda _: (
+                jnp.full((qc, out_width), PAD, jnp.int32),
+                jnp.full((qc,), NOKEY, jnp.int32)), o)
+
+    def write(o):
+        q = jnp.minimum(jnp.searchsorted(incl, o, side="right"), S * G - 1)
+        s, g = q // G, q % G
+        k = o - (incl[q] - per[q])
+        cand = jnp.take(cmeta, s, axis=0)                        # (qc, 5)
+        p = cand[:, 0]
+        # dense parent rows of the pair: the m-th row holds child k
+        base_d = (s * G + g) * (Mp + 1)
+        m = _last_le(lambda i: jnp.take(cdx_flat, base_d + i),
+                     jnp.zeros_like(k), jnp.full_like(k, Mp), k,
+                     (Mp + 1).bit_length())
+        j_d = k - jnp.take(cdx_flat, base_d + m)
+        row_d = jnp.take(pol_flat, (p * G + g) * Mp + m, axis=0)
+        # spilled parent rows of the pair: rows [b0, b1) of the table
+        b0 = jnp.take(bounds.reshape(-1), p * (G + 1) + g)
+        b1 = jnp.take(bounds.reshape(-1), p * (G + 1) + g + 1)
+        base_s = jnp.take(csx_flat, b0 * S + s)
+        r = _last_le(lambda i: jnp.take(csx_flat, i * S + s), b0,
+                     jnp.maximum(b1, b0 + 1), base_s + k,
+                     (R + 1).bit_length())
+        j_s = base_s + k - jnp.take(csx_flat, r * S + s)
+        row_s = jnp.take(rows, jnp.minimum(r, R - 1), axis=0)
+        from_spill = (b1 > b0)[:, None]
+        row = jnp.where(from_spill, row_s, row_d)
+        j = jnp.where(from_spill[:, 0], j_s, j_d)
+        ok, nb = _ext_one(row, g, cand, nbr, code)
+        d = jnp.argmax(ok & (jnp.cumsum(ok, axis=-1) == (j + 1)[:, None]),
+                       axis=-1)
+        new_v = jnp.take_along_axis(nb, d[:, None], axis=1)[:, 0]
+        child = jnp.concatenate(
+            [row, jnp.full((qc, out_width - W), PAD, jnp.int32)], axis=1)
+        fwd = cand[:, 3].astype(bool)
+        child = jnp.where((jnp.arange(out_width) == cand[:, 2:3])
+                          & fwd[:, None], new_v[:, None], child)
+        live = o < total
+        return (jnp.where(live[:, None], child, PAD),
+                jnp.where(live, s * G + g, NOKEY).astype(jnp.int32))
+
+    out, keys = jax.lax.map(chunk, jnp.arange(out_rows).reshape(-1, qc))
+    return out.reshape(out_rows, out_width), keys.reshape(out_rows)

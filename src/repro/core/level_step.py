@@ -126,7 +126,7 @@ from .mapreduce import MiningMesh, reduce_supports, worker_imbalance
 __all__ = ["LevelWire", "LevelOutputs", "PendingLevel", "dispatch_level",
            "run_level", "unpack_wire", "reassemble_wire", "wire_words",
            "wire_cost_model", "lpt_permutation", "wire_checksum",
-           "fetch_wire", "AUDIT_MONOTONIC", "AUDIT_COMPACT",
+           "fetch_wire", "schedule_args", "AUDIT_MONOTONIC", "AUDIT_COMPACT",
            "AUDIT_RANGE", "AUDIT_NKEEP"]
 
 _IMBAL_FX = 1 << 16
@@ -734,34 +734,8 @@ def dispatch_level(
             n_par = min(len(psup), P_axis)
             psup_p[:n_par] = np.asarray(psup, np.int32)[:n_par]
         psup_d = jnp.asarray(psup_p)
-        if is_fused_backend(backend):
-            from ..kernels.fused_level import DEFAULT_TILE_C
-            from .buckets import bucket_size
-            from .candgen import pad_schedule, schedule_candidates
-            tc = tile_c if tile_c is not None else DEFAULT_TILE_C
-            # only the real rows are scheduled (padded candidates would
-            # fragment the parent grouping); the row axis is then
-            # bucketed with whole invalid tiles and inv parked on one of
-            # them.  The bucketed schedule PINS tile_c: the adaptive
-            # halving picks a different width per level (a different
-            # kernel grid — a recompile); partial-tile waste is bounded
-            # by the row bucket and fully-invalid tiles are skipped
-            # inside the kernel.  The mining loop's run-level pin
-            # (``tile_c``) replaces the hardwired 8 with the level-2
-            # grouping's adaptive choice.
-            if sched_floor is not None:
-                sched = schedule_candidates(np.asarray(meta_p)[:C_real], tc,
-                                            max_inflation=float("inf"))
-                rows = bucket_size(sched.meta.shape[0], sched_floor)
-            else:
-                sched = schedule_candidates(np.asarray(meta_p)[:C_real], tc)
-                rows = sched.meta.shape[0]
-            sched = pad_schedule(sched, rows_to=rows, inv_to=Cp)
-            sched_span.set(rows=rows, tile_c=sched.tile_c)
-            meta_args = (jnp.asarray(sched.meta), jnp.asarray(sched.tiles),
-                         jnp.asarray(sched.inv))
-        else:
-            meta_args = (jnp.asarray(meta_p),)
+        meta_args = schedule_args(meta_p, C_real, backend, tile_c,
+                                  sched_floor, sched_span)
     with tracing.Span("dispatch", counts={"compiles": "compiles"}):
         wire_d, new_pol, new_pmask = fn(c_real, psup_d, *meta_args,
                                         pol, pmask, src, dst, emask)
@@ -769,6 +743,42 @@ def dispatch_level(
                         C_real, Cp, n_partitions,
                         W if sharded else 1, level, packed,
                         sched_span.start_ns)
+
+
+def schedule_args(meta_p: np.ndarray, C_real: int, backend: Backend,
+                  tile_c: Optional[int], sched_floor: Optional[int],
+                  span: tracing.Span) -> tuple:
+    """The candidate arguments of a level's support stage: the padded
+    canonical ``meta_p`` itself, or for the fused backends the
+    parent-grouped tile schedule ``(meta, tiles, inv)`` of its first
+    ``C_real`` rows (set as the ``rows``/``tile_c`` args of ``span``)."""
+    if not is_fused_backend(backend):
+        return (jnp.asarray(meta_p),)
+    from ..kernels.fused_level import DEFAULT_TILE_C
+    from .buckets import bucket_size
+    from .candgen import pad_schedule, schedule_candidates
+    Cp = meta_p.shape[0]
+    tc = tile_c if tile_c is not None else DEFAULT_TILE_C
+    # only the real rows are scheduled (padded candidates would
+    # fragment the parent grouping); the row axis is then bucketed
+    # with whole invalid tiles and inv parked on one of them.  The
+    # bucketed schedule PINS tile_c: the adaptive halving picks a
+    # different width per level (a different kernel grid — a
+    # recompile); partial-tile waste is bounded by the row bucket and
+    # fully-invalid tiles are skipped inside the kernel.  The mining
+    # loop's run-level pin (``tile_c``) replaces the hardwired 8 with
+    # the level-2 grouping's adaptive choice.
+    if sched_floor is not None:
+        sched = schedule_candidates(np.asarray(meta_p)[:C_real], tc,
+                                    max_inflation=float("inf"))
+        rows = bucket_size(sched.meta.shape[0], sched_floor)
+    else:
+        sched = schedule_candidates(np.asarray(meta_p)[:C_real], tc)
+        rows = sched.meta.shape[0]
+    sched = pad_schedule(sched, rows_to=rows, inv_to=Cp)
+    span.set(rows=rows, tile_c=sched.tile_c)
+    return (jnp.asarray(sched.meta), jnp.asarray(sched.tiles),
+            jnp.asarray(sched.inv))
 
 
 def run_level(*args, **kwargs) -> LevelOutputs:

@@ -42,10 +42,12 @@ from ..kernels.ops import (Backend, default_backend, device_local_supports,
                            is_fused_backend, is_packed_backend)
 from ..runtime import jax_compat
 from .candgen import schedule_candidates
-from .embedding import materialize_prefix
+from .embedding import (materialize_prefix, spill_child_counts,
+                        spill_support_rows, spill_write)
 
 __all__ = ["MiningMesh", "map_reduce_supports", "map_materialize",
-           "reduce_supports", "worker_imbalance"]
+           "reduce_supports", "worker_imbalance", "spill_supports",
+           "spill_counts", "spill_materialize"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -281,3 +283,140 @@ def map_materialize(
     fn = _materialize_program(mmesh, max_embeddings, out_width)
     ol, mask, overflow = fn(keep_meta, pol, pmask, src, dst, emask)
     return ol, mask, int(np.asarray(overflow))
+
+
+# ---------------------------------------------------------------------------
+# The spill store's level (embedding_store="spill", DESIGN.md §15): a
+# support round, then a count pass and a write pass of materialization.
+# Three programs, two small host syncs — the survivors, and the child
+# spill table's size — where the dense store's level is one program.
+# ---------------------------------------------------------------------------
+
+@functools.lru_cache(maxsize=64)
+def _spill_support_program(mmesh: MiningMesh, backend: Backend,
+                           packed: bool):
+    """Global supports over the dense store AND the spill table: the
+    dense kernel's local count plus, per candidate, the graphs in which
+    a spilled pair of its parent matches (the two hold disjoint pairs),
+    psummed over the workers."""
+    axes = mmesh.axes
+    parts = mmesh.spec_parts()
+    rep = mmesh.replicated()
+    fused = is_fused_backend(backend)
+    interpret = backend.endswith("interpret")
+
+    def program(meta, by_parent, cpos, *args):
+        *sched, pol, pmask, src, dst, emask, rows, key, nbr, code = args
+        with jax.named_scope("mirage/support_kernel"):
+            if fused:
+                kernel = (fused_level_supports_packed if packed
+                          else fused_level_supports)
+                sched_meta, tiles, inv = sched
+                sup_pp, _ = kernel(sched_meta, tiles, pol, pmask, src, dst,
+                                   emask, interpret=interpret)
+                local = jnp.take(sup_pp.sum(0), inv)
+            else:
+                local, _, _ = device_local_supports(
+                    meta, pol, pmask, src, dst, emask, backend=backend,
+                    packed=packed)
+        with jax.named_scope("mirage/spill_support"):
+            extra = jax.lax.map(
+                lambda xs: spill_support_rows(
+                    meta, by_parent, cpos, *xs, n_graphs=pol.shape[2],
+                    n_triples=src.shape[1]),
+                (rows, key, nbr, code)).sum(0)
+        with jax.named_scope("mirage/reduce"):
+            return jax.lax.psum(local + extra, axes)
+
+    n_sched = 3 if fused else 0
+    return jax.jit(jax_compat.shard_map(
+        program, mesh=mmesh.mesh,
+        in_specs=(rep,) * (3 + n_sched) + (parts,) * 9,
+        out_specs=rep, check_vma=False))
+
+
+def spill_supports(mmesh: MiningMesh, meta_p: np.ndarray,
+                   sched_args: tuple, by_parent: np.ndarray,
+                   cpos: np.ndarray, pol, pmask, src, dst, emask, spill,
+                   adjacency, *, backend: Backend, packed: bool):
+    """Dispatch the spill store's support round; returns the (Cp,)
+    global supports as a device array (not yet fetched).  ``sched_args``
+    are the fused schedule's arrays (``level_step.schedule_args``), or
+    the metadata itself for the other backends."""
+    fused = is_fused_backend(backend)
+    fn = _spill_support_program(mmesh, backend, packed)
+    return fn(jnp.asarray(meta_p), jnp.asarray(by_parent),
+              jnp.asarray(cpos), *(sched_args if fused else ()),
+              pol, pmask, src, dst, emask, *spill, *adjacency)
+
+
+@functools.lru_cache(maxsize=64)
+def _spill_count_program(mmesh: MiningMesh, max_embeddings: int,
+                         n_triples: int):
+    parts = mmesh.spec_parts()
+    rep = mmesh.replicated()
+
+    @jax.named_scope("mirage/spill_materialize")
+    def program(cmeta, n_fill, pol, pmask, rows, key, nbr, code):
+        return jax.lax.map(
+            lambda xs: spill_child_counts(
+                cmeta, n_fill, *xs, n_triples=n_triples,
+                max_embeddings=max_embeddings),
+            (pol, pmask, rows, key, nbr, code))
+
+    return jax.jit(jax_compat.shard_map(
+        program, mesh=mmesh.mesh, in_specs=(rep, rep) + (parts,) * 6,
+        out_specs=(parts,) * 6, check_vma=False))
+
+
+def spill_counts(mmesh: MiningMesh, cmeta: np.ndarray, n_fill: int, pol,
+                 pmask, spill, adjacency, *, max_embeddings: int,
+                 n_triples: int):
+    """The count pass of a spill level's materialization for the
+    survivors ``cmeta`` (S, 5), the first ``n_fill`` real.  Returns the
+    device-resident counts for :func:`spill_materialize` and, fetched,
+    each partition's child spill rows and pairs."""
+    fn = _spill_count_program(mmesh, max_embeddings, n_triples)
+    out = fn(jnp.asarray(cmeta), jnp.int32(n_fill), pol, pmask, *spill,
+             *adjacency)
+    return out[:4], np.asarray(out[4]), np.asarray(out[5])
+
+
+@functools.lru_cache(maxsize=64)
+def _spill_write_program(mmesh: MiningMesh, max_embeddings: int,
+                         out_width: int, out_rows: int):
+    parts = mmesh.spec_parts()
+    rep = mmesh.replicated()
+
+    def program(cmeta, n_fill, cdx, csx, bounds, spilled, pol, pmask, src,
+                dst, emask, rows, key, nbr, code):
+        with jax.named_scope("mirage/materialize"):
+            # a child pair headed for the spill keeps no dense rows
+            ol, mask, _ = materialize_prefix(
+                cmeta, n_fill, pol, pmask, src, dst, emask,
+                n_slots=cmeta.shape[0], max_embeddings=max_embeddings,
+                out_width=out_width)
+            mask = mask & ~spilled[..., None]
+            ol = jnp.where(mask[..., None], ol, -1)
+        with jax.named_scope("mirage/spill_materialize"):
+            srows, skey = jax.lax.map(
+                lambda xs: spill_write(cmeta, *xs, out_rows=out_rows,
+                                       out_width=out_width),
+                (cdx, csx, bounds, spilled, pol, rows, key, nbr, code))
+        return ol, mask, srows, skey
+
+    return jax.jit(jax_compat.shard_map(
+        program, mesh=mmesh.mesh, in_specs=(rep, rep) + (parts,) * 13,
+        out_specs=(parts,) * 4, check_vma=False))
+
+
+def spill_materialize(mmesh: MiningMesh, cmeta: np.ndarray, n_fill: int,
+                      counts, pol, pmask, src, dst, emask, spill,
+                      adjacency, *, max_embeddings: int, out_width: int,
+                      out_rows: int):
+    """The write pass: the survivors' dense child store (NP, S, G, M, W)
+    and mask, and their child spill table (NP, out_rows, W) and keys,
+    all device-resident."""
+    fn = _spill_write_program(mmesh, max_embeddings, out_width, out_rows)
+    return fn(jnp.asarray(cmeta), jnp.int32(n_fill), *counts, pol, pmask,
+              src, dst, emask, *spill, *adjacency)

@@ -45,6 +45,7 @@ replacing Hadoop's speculative execution.
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Optional, Sequence
 
 import jax
@@ -64,10 +65,13 @@ from .candgen import (Candidate, EdgeAlphabet, candidates_from_arrays,
                       device_candgen_jit, filter_speculative,
                       generate_candidates, schedule_candidates)
 from .dfscode import Code, array_to_code, code_to_array
-from .embedding import build_edge_ol, candidate_meta, level1_ol
+from .embedding import (build_adjacency, build_edge_ol, candidate_meta,
+                        empty_spill, level1_ol)
 from .graphdb import Graph
-from .level_step import _IMBAL_FX, dispatch_level, fetch_wire, permute_stores
-from .mapreduce import MiningMesh, map_materialize, map_reduce_supports
+from .level_step import (_IMBAL_FX, dispatch_level, fetch_wire,
+                         permute_stores, schedule_args)
+from .mapreduce import (MiningMesh, map_materialize, map_reduce_supports,
+                        spill_counts, spill_materialize, spill_supports)
 from .partition import make_partitions
 
 __all__ = ["MirageConfig", "LevelStats", "DistMiningResult",
@@ -76,6 +80,13 @@ __all__ = ["MirageConfig", "LevelStats", "DistMiningResult",
 
 PIPELINES = ("single_sync", "device_loop", "legacy")
 CANDGENS = ("host", "device")
+STORES = ("dense", "spill")
+
+# the spill store's table rows R (1024·4^i, never shrinking within a fit:
+# chunks past the last used row cost nothing, a new R costs a compile)
+# and its candidates per parent (bucketed from 8)
+_SPILL_R_FLOOR = 1024
+_SPILL_KC_FLOOR = 8
 
 # child stores a level may hold at once, in units of the cap's store:
 # the program's own (S slots at M) plus a retry's beside it (S slots at
@@ -137,6 +148,13 @@ class MirageConfig:
     max_size: Optional[int] = None      # max pattern edges (None = to fixpoint)
     max_embeddings: int = 32            # M cap (exactness valve escalates)
     max_embeddings_limit: int = 512     # escalation ceiling
+    # the embedding store (DESIGN.md §15): "dense" holds M embeddings a
+    # (pattern, graph) pair and escalates M on overflow up to the
+    # ceiling, past which it cuts embeddings off and reports them in
+    # total_overflow; "spill" (single_sync only) keeps M fixed and holds
+    # every pair past it, whole, in a row table that follows the real
+    # embeddings — exact at any size, total_overflow always 0
+    embedding_store: str = "dense"
     max_occ: Optional[int] = None       # F pad (None = derive from data)
     backend: Optional[Backend] = None   # kernels backend (None = auto)
     # shuffle collective; None resolves per pipeline in __post_init__:
@@ -231,6 +249,19 @@ class MirageConfig:
         if self.candgen not in CANDGENS:
             raise ValueError(f"candgen={self.candgen!r} must be one of "
                              f"{CANDGENS}")
+        if self.embedding_store not in STORES:
+            raise ValueError(f"embedding_store={self.embedding_store!r} "
+                             f"must be one of {STORES}")
+        if self.embedding_store == "spill":
+            if self.pipeline != "single_sync":
+                raise ValueError(
+                    f"embedding_store='spill' runs on pipeline="
+                    f"'single_sync' only, got {self.pipeline!r}")
+            if self.checkpoint_dir:
+                raise ValueError(
+                    "embedding_store='spill' takes no checkpoint_dir — a "
+                    "checkpoint holds the dense store only, and the spill "
+                    "table would not survive a resume")
         if self.n_partitions < 1:
             raise ValueError(
                 f"n_partitions={self.n_partitions} must be >= 1")
@@ -287,6 +318,10 @@ class LevelStats:
     candgen_seconds: float = 0.0
     survivor_cap: int = 0               # S the level program compacted into
     retried: bool = False               # level took a materialize-only retry
+    # rows and (pattern, graph) pairs the spill store's table holds after
+    # the level (0 under the dense store)
+    spill_rows: int = 0
+    spill_pairs: int = 0
 
 
 @dataclasses.dataclass
@@ -381,6 +416,11 @@ class _LevelOutcome:
     # the speculation gate's decision: "taken", "skipped" (the estimate
     # outran its window) or "none" (no speculation attempted)
     spec: str = "none"
+    # the spill store's next-level table (rows, keys) and its size
+    # (None / 0 under the dense store)
+    spill: Optional[tuple] = None
+    spill_rows: int = 0
+    spill_pairs: int = 0
 
 
 class Mirage:
@@ -500,6 +540,7 @@ class Mirage:
         # ---- phase 2: preparation (host, once) -------------------------
         G = max((len(p) for p in part.partitions), default=1)
         backend = cfg.backend or default_backend()
+        spilling = cfg.embedding_store == "spill"
         if is_fused_backend(backend) and not backend.endswith("interpret"):
             # a lane-aligned graph axis keeps G minor-most in XLA's TPU
             # layout of every store, so the kernel's graph-minor views
@@ -514,6 +555,9 @@ class Mirage:
             src = np.stack([_pad_f(e.src, F, -1) for e in eols])   # (NP,T,G,F)
             dst = np.stack([_pad_f(e.dst, F, -1) for e in eols])
             emask = np.stack([_pad_f(e.mask, F, False) for e in eols])
+            # the spill join's view of the same occurrences
+            adjacency = (build_adjacency(src, dst, emask) if spilling
+                         else ())
             sp.set(F=F)
         eol0 = eols[0]   # triple_index identical across partitions
 
@@ -559,8 +603,13 @@ class Mirage:
             # may have used different floors (or none)
             pol, pmask = self._repad_saved(pol, pmask)
 
-        pol, pmask, src_d, dst_d, emask_d = self._device_put(
-            pol, pmask, src, dst, emask)
+        extra = ()
+        if spilling:
+            extra = (*empty_spill(n_parts, pol.shape[-1], _SPILL_R_FLOOR),
+                     *adjacency)
+        (pol, pmask, src_d, dst_d, emask_d, *extra) = self._device_put(
+            pol, pmask, src, dst, emask, *extra)
+        spill, adjacency = tuple(extra[:2]), tuple(extra[2:])
 
         # cumulative partition permutation from straggler rebalancing;
         # checkpoints always store the OL store in CANONICAL order so a
@@ -675,25 +724,34 @@ class Mirage:
                     # the stretch a hang would otherwise block unobserved
                     wd.arm(level=k + 1)
 
+                if (tile_pin is None and bk is not None
+                        and cfg.pipeline != "legacy"
+                        and is_fused_backend(cfg.backend)):
+                    # level 2 is the widest, most parent-diverse grouping
+                    # the run will see — its adaptive choice generalizes;
+                    # later levels reuse it so the schedule shapes (and
+                    # the compiled level program) stay fixed
+                    with tracing.Span("schedule") as sp:
+                        tile_pin = schedule_candidates(meta).tile_c
+                        sp.set(tile_c=tile_pin)
                 if cfg.pipeline == "legacy":
                     out = self._level_legacy(
                         meta_p, meta, C, pol, pmask, src_d, dst_d, emask_d,
                         minsup, M, n_parts, level=k + 1)
+                elif spilling:
+                    out = self._level_spill(
+                        meta_p, meta, C, pol, pmask, src_d, dst_d, emask_d,
+                        spill, adjacency, minsup,
+                        (bk.vertex_slots(k + 2, int(pol.shape[-1]))
+                         if bk is not None else None),
+                        packed=packed, tile_c=tile_pin)
+                    spill = out.spill
                 else:
                     # child patterns (size k+1) have at most k+2 vertices;
                     # the bucketed width reuses the parent store's while the
                     # child still fits, so the arena shape repeats
                     child_width = (bk.vertex_slots(k + 2, int(pol.shape[-1]))
                                    if bk is not None else None)
-                    if (tile_pin is None and bk is not None
-                            and is_fused_backend(cfg.backend)):
-                        # level 2 is the widest, most parent-diverse grouping
-                        # the run will see — its adaptive choice generalizes;
-                        # later levels reuse it so the schedule shapes (and
-                        # the compiled level program) stay fixed
-                        with tracing.Span("schedule") as sp:
-                            tile_pin = schedule_candidates(meta).tile_c
-                            sp.set(tile_c=tile_pin)
                     try:
                         out = self._level_single_sync(
                             meta_p, meta, C, pol, pmask, src_d, dst_d, emask_d,
@@ -718,7 +776,11 @@ class Mirage:
                     policy.record(out.retried)
                 lvl.set(S=out.survivor_cap, M=out.max_embeddings,
                         donated=int(out.donated), retried=int(out.retried),
-                        escalations=out.escalations, spec=out.spec)
+                        escalations=out.escalations, spec=out.spec,
+                        spill_rows=out.spill_rows,
+                        spill_pairs=out.spill_pairs)
+                tracing.count("spill_rows", out.spill_rows)
+                tracing.count("spill_pairs", out.spill_pairs)
                 if wd is not None:
                     # feed the level's wall-time into the EWMA the next
                     # phase deadline is derived from
@@ -762,7 +824,9 @@ class Mirage:
                                         out.imbalance, out.escalations,
                                         out.candgen_seconds,
                                         survivor_cap=out.survivor_cap,
-                                        retried=out.retried))
+                                        retried=out.retried,
+                                        spill_rows=out.spill_rows,
+                                        spill_pairs=out.spill_pairs))
 
                 if cfg.checkpoint_dir:
                     self._save(cfg.checkpoint_dir, k + 1, levels, supports,
@@ -1314,6 +1378,70 @@ class Mirage:
             donated=donated, spec=spec)
 
     # ------------------------------------------------------------------
+    def _level_spill(self, meta_p, meta, C, pol, pmask, src, dst, emask,
+                     spill, adjacency, minsup,
+                     child_width: Optional[int] = None, *,
+                     packed: bool = False,
+                     tile_c: Optional[int] = None) -> _LevelOutcome:
+        """One level over the spill store (DESIGN.md §15): the support
+        round over the dense store and the spill table, the survivors
+        fetched, then the materialization's count pass (its only fetch is
+        the child table's size, which sizes the write pass) and write
+        pass.  M stays fixed: no escalation, no retry, and nothing is
+        cut off.  The partition order never changes (no rebalance)."""
+        cfg = self.cfg
+        bk = self._buckets()
+        backend = cfg.backend or default_backend()
+        Cp = meta_p.shape[0]
+        M = cfg.max_embeddings
+        with tracing.Span("schedule") as sched_span:
+            by_parent, cpos = _by_parent(meta, pol.shape[1], Cp)
+            sched = schedule_args(meta_p, C, backend, tile_c,
+                                  bk.c_floor if bk is not None else None,
+                                  sched_span)
+        with tracing.Span("dispatch", counts={"compiles": "compiles"}):
+            gsup_d = spill_supports(
+                self.mesh, meta_p, sched, by_parent, cpos, pol, pmask, src,
+                dst, emask, spill, adjacency, backend=backend,
+                packed=packed)
+        with tracing.Span("wire_wait"):
+            gsup_d.block_until_ready()
+        with tracing.Span("wire_decode"):
+            gsup = np.asarray(gsup_d)[:C]
+        keep = np.flatnonzero(gsup >= minsup)
+        out = _LevelOutcome(
+            gsup=gsup, keep=keep, pol=pol, pmask=pmask, src=src, dst=dst,
+            emask=emask, overflow=0, max_embeddings=M, rebalanced=False,
+            imbalance=1.0, perm=None, map_seconds=0.0, escalations=0,
+            spill=spill)
+        if len(keep):
+            n = len(keep)
+            S = bk.survivors(n, Cp) if bk is not None else n
+            cmeta = np.concatenate(
+                [meta[keep], np.tile([[0, 0, 0, 1, 0]], (S - n, 1))]
+            ).astype(np.int32)
+            width = (child_width if child_width is not None
+                     else int(pol.shape[-1]) + 1)
+            with tracing.Span("spill_count") as sp:
+                counts, rows, pairs = spill_counts(
+                    self.mesh, cmeta, n, pol, pmask, spill, adjacency,
+                    max_embeddings=M, n_triples=int(src.shape[1]))
+                out.spill_rows = int(rows.sum())
+                out.spill_pairs = int(pairs.sum())
+                sp.set(rows=out.spill_rows, pairs=out.spill_pairs)
+            with tracing.Span("spill_write"):
+                (out.pol, out.pmask, *new_spill) = spill_materialize(
+                    self.mesh, cmeta, n, counts, pol, pmask, src, dst,
+                    emask, spill, adjacency, max_embeddings=M,
+                    out_width=width,
+                    out_rows=_spill_capacity(rows,
+                                             int(spill[0].shape[1])))
+            out.spill = tuple(new_spill)
+            out.survivor_cap = S
+        out.map_seconds = (time.time_ns() - sched_span.start_ns) / 1e9
+        return out
+
+    # ------------------------------------------------------------------
     def _level_legacy(self, meta_p, meta, C, pol, pmask, src, dst, emask,
                       minsup, M, n_parts, *,
                       level: Optional[int] = None) -> _LevelOutcome:
@@ -1377,9 +1505,9 @@ class Mirage:
                 M = min(M * 2, cfg.max_embeddings_limit)
                 escalations += 1
 
-    def _device_put(self, pol, pmask, src, dst, emask):
+    def _device_put(self, pol, pmask, src, dst, emask, *extra):
         sharding = partition_sharding(self.mesh.mesh)
-        arrays = (pol, pmask, src, dst, emask)
+        arrays = (pol, pmask, src, dst, emask, *extra)
         with tracing.Span("upload", bytes=sum(x.nbytes for x in arrays)):
             return tuple(jax.device_put(jnp.asarray(x), sharding)
                          for x in arrays)
@@ -1460,6 +1588,39 @@ def _pad_store(pol, pmask, *, p_to: Optional[int] = None,
     pol = pad(pad(pad(pad(pol, 1, p_to), 2, g_to), 3, m_to), 4, k_to)
     pmask = pad(pad(pad(pmask, 1, p_to), 2, g_to), 3, m_to)
     return pol, pmask
+
+
+def _spill_capacity(rows: np.ndarray, current: int) -> int:
+    """Rows of the next spill table: the smallest 1024·4^i holding every
+    partition's ``rows`` and twice their mean, never fewer than the
+    ``current`` table's.  The mean is the same whatever the partitioning
+    (every seed of a DB holds the same embeddings), so the sizes, and
+    the programs compiled for them, rarely move with the graphs' order."""
+    need = max(int(rows.max()), -(-2 * int(rows.sum()) // len(rows)))
+    r = _SPILL_R_FLOOR
+    while r < need:
+        r *= 4
+    return max(r, current)
+
+
+def _by_parent(meta: np.ndarray, n_parents: int, Cp: int
+               ) -> tuple[np.ndarray, np.ndarray]:
+    """The candidates of each parent, for the spill join: ``by_parent``
+    (n_parents, Kc) candidate indices, -1 padded, Kc bucketed; and
+    ``cpos`` (Cp,) each candidate's flat index ``parent·Kc + j`` there,
+    -1 past the C real ones."""
+    parent = meta[:, 0].astype(np.int64)
+    C = len(parent)
+    order = np.argsort(parent, kind="stable")
+    first = np.searchsorted(parent[order], parent[order], side="left")
+    j = np.empty(C, np.int64)
+    j[order] = np.arange(C) - first
+    kc = bucket_size(int(j.max()) + 1 if C else 1, _SPILL_KC_FLOOR)
+    by_parent = np.full((n_parents, kc), -1, np.int32)
+    by_parent[parent, j] = np.arange(C)
+    cpos = np.full(Cp, -1, np.int32)
+    cpos[:C] = parent * kc + j
+    return by_parent, cpos
 
 
 def _pad_f(a: np.ndarray, F: int, fill) -> np.ndarray:

@@ -435,7 +435,8 @@ def _degrade(cfg: MirageConfig, rung: str) -> MirageConfig:
     for the two-launch backend ("pallas" on TPU, its "interpret" twin
     elsewhere).  "legacy" falls all the way back to the host-driven
     pipeline on the "ref" backend — the differential oracle, which
-    dispatches no custom kernel at all.
+    dispatches no custom kernel at all; a spill-store run, which only
+    single_sync can mine, takes the "ref" backend on its own pipeline.
     """
     import jax
 
@@ -450,6 +451,8 @@ def _degrade(cfg: MirageConfig, rung: str) -> MirageConfig:
         return dataclasses.replace(
             cfg, pipeline=pipeline,
             backend="pallas" if on_tpu else "interpret")
+    if cfg.embedding_store == "spill":
+        return dataclasses.replace(cfg, backend="ref", packed_support=None)
     return dataclasses.replace(cfg, pipeline="legacy", backend="ref",
                                packed_support=None)
 

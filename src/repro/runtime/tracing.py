@@ -14,13 +14,14 @@ The phases (args in brackets; "levels"/"compiles"/... at close):
 
   fit               the whole fit [n_graphs, minsup, pipeline; levels,
                     compiles, wire_fetches, gc_gen2, gc_s, canon_tested,
-                    canon_early]
+                    canon_early, spill_rows, spill_pairs]
   partition         ``make_partitions`` [n_parts]
   edge_ol_build     the per-partition edge OLs, padded and stacked [F]
   level1            the level-1 OLs and supports [codes]
   upload            the stores' host-to-device copy [bytes]
   level             one level of the mining loop [level, candidates, Cp,
-                    S, M, donated, retried, escalations, spec, compiles]
+                    S, M, donated, retried, escalations, spec, spill_rows,
+                    spill_pairs, compiles]
   candgen           the loop-head candidates: generated, or narrowed from
                     the previous level's speculation [parents, candidates;
                     tested, early]
@@ -34,6 +35,9 @@ The phases (args in brackets; "levels"/"compiles"/... at close):
   wire_wait         waiting for the device to finish the level's wire
   wire_decode       the wire's transfer, checksum and decode [attempts]
   retry_materialize a materialize-only retry [escalations, M]
+  spill_count       the spill store's count pass and its fetch of the
+                    child table's size [rows, pairs]
+  spill_write       the spill store's write pass (its dispatch)
   permute           the rebalance's store permutation
   audit             the auditor's host checks
   checkpoint        a checkpoint save
@@ -53,12 +57,17 @@ one's change over the span as an arg:
                 candidates were narrowed from a speculation)
   canon_early   those of them the walk rejected before their last
                 position, on a smaller prefix (span arg ``early``)
+  spill_rows    rows the spill store's table holds after each level,
+                summed over the levels (level span arg, per level)
+  spill_pairs   (pattern, graph) pairs it holds, likewise
 
 Device work is named by ``jax.named_scope`` inside the programs
 (``mirage/support_kernel``, ``mirage/reduce``, ``mirage/compact``,
 ``mirage/audit``, ``mirage/materialize``, ``mirage/wire_pack``,
-``mirage/permute``): the scope rides in each HLO op's ``op_name``
-metadata, and changes nothing else of the compiled program.
+``mirage/permute``, and under the spill store ``mirage/spill_support``
+and ``mirage/spill_materialize``, never inside another ``mirage/``
+scope): the scope rides in each HLO op's ``op_name`` metadata, and
+changes nothing else of the compiled program.
 """
 from __future__ import annotations
 
@@ -75,7 +84,8 @@ PREFIX = "mirage:"
 _LOWERING = "/jax/core/compile/jaxpr_to_mlir_module_duration"
 
 _totals = {"compiles": 0, "wire_fetches": 0, "gc_gen2": 0, "gc_s": 0.0,
-           "canon_tested": 0, "canon_early": 0}
+           "canon_tested": 0, "canon_early": 0, "spill_rows": 0,
+           "spill_pairs": 0}
 
 #: the counters the candgen spans report, as args ``tested`` and ``early``
 CANON_COUNTS = {"tested": "canon_tested", "early": "canon_early"}

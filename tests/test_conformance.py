@@ -112,6 +112,50 @@ def test_escalation_valve_respects_ceiling():
     assert res.total_overflow > 0
 
 
+@pytest.mark.parametrize("max_embeddings", [2, 4])
+@pytest.mark.parametrize("n_partitions", [1, 2])
+def test_spill_store_is_exact_below_the_need(n_partitions, max_embeddings):
+    """The adversarial DB under embedding_store="spill": a fixed M far
+    below the need spills most pairs, and the answer is still the
+    oracle's, with nothing cut off."""
+    graphs = random_db(8, n_vertices=8, extra_edge_prob=0.9, n_vlabels=1,
+                       n_elabels=1, seed=7)
+    ref = mine_host(graphs, 4, max_size=3)
+    cfg = MirageConfig(minsup=4, n_partitions=n_partitions, max_size=3,
+                       max_embeddings=max_embeddings,
+                       embedding_store="spill")
+    res = Mirage(cfg).fit(graphs)
+    assert [set(l) for l in res.levels] == [set(l) for l in ref.levels]
+    assert canon_dist(res) == canon_host(ref)
+    assert res.total_overflow == 0
+    assert sum(st.spill_rows for st in res.stats) > 0
+    assert all(st.escalations == 0 for st in res.stats)
+
+
+@pytest.mark.parametrize("pipeline", ["device_loop", "legacy"])
+def test_spill_store_needs_single_sync(pipeline):
+    with pytest.raises(ValueError, match="single_sync"):
+        MirageConfig(minsup=4, max_size=3, pipeline=pipeline,
+                     embedding_store="spill")
+
+
+def test_spill_store_degrades_on_its_own_pipeline():
+    """The supervisor's last rung drops a spill-store run to the "ref"
+    backend on single_sync, the one pipeline that mines it."""
+    from repro.core.supervisor import _degrade
+
+    cfg = _degrade(MirageConfig(minsup=4, embedding_store="spill"),
+                   "legacy")
+    assert (cfg.pipeline, cfg.backend, cfg.embedding_store) == (
+        "single_sync", "ref", "spill")
+
+
+def test_spill_store_takes_no_checkpoints(tmp_path):
+    with pytest.raises(ValueError, match="checkpoint_dir"):
+        MirageConfig(minsup=4, checkpoint_dir=str(tmp_path),
+                     embedding_store="spill")
+
+
 # ---------------------------------------------------------------------------
 # checkpoint/resume across bucket boundaries (runtime/checkpoint.py
 # padding round-trips: save and resume may disagree on bucket floors —

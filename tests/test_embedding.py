@@ -4,9 +4,12 @@ import numpy as np
 import pytest
 
 from repro.core.candgen import generate_candidates
-from repro.core.embedding import (build_edge_ol, candidate_meta, join_valid,
-                                  level1_ol, local_supports_ref,
-                                  materialize_prefix, LevelOL)
+from repro.core.buckets import bucket_size
+from repro.core.embedding import (NOKEY, build_adjacency, build_edge_ol,
+                                  candidate_meta, join_valid, level1_ol,
+                                  local_supports_ref, materialize_prefix,
+                                  materialize_rows, spill_child_counts,
+                                  spill_write, LevelOL)
 from repro.core.graphdb import paper_toy_db, random_db
 from repro.core.host_miner import frequent_edges, mine_host
 
@@ -103,3 +106,87 @@ def test_join_valid_backward_semantics():
     v = np.asarray(valid)
     assert v[0, 0, 0] and not v[0, 0, 1]   # emb (0,1,2): occ (2,0) closes it
     assert not v[0, 1].any()               # emb (1,2,3): no 3->1 edge occ
+
+
+def _spill_layout(ol, mask, m):
+    """A compact dense store (P, G, Mbig, K) in the spill layout at M=m:
+    each pair with more than m embeddings moves whole to the key-sorted
+    spill table, and its dense slots are masked."""
+    P, G, _, K = ol.shape
+    spilled = mask.sum(-1) > m
+    keep = mask & ~spilled[..., None]
+    dense_ol = np.where(keep[..., None], ol, -1)[:, :, :m]
+    rows = [ol[p, g, r] for p in range(P) for g in range(G)
+            for r in np.flatnonzero(mask[p, g]) if spilled[p, g]]
+    keys = [p * G + g for p in range(P) for g in range(G)
+            for _ in np.flatnonzero(mask[p, g]) if spilled[p, g]]
+    R = bucket_size(max(len(rows), 1), 8)
+    table = np.full((R, K), -1, np.int32)
+    key = np.full((R,), NOKEY, np.int32)
+    table[:len(rows)] = rows
+    key[:len(keys)] = keys
+    return dense_ol, keep[:, :, :m], table, key
+
+
+def _by_graph(rows, graphs):
+    out = {}
+    for r, g in zip(np.asarray(rows).tolist(), np.asarray(graphs).tolist()):
+        out.setdefault(g, []).append(tuple(r))
+    return {g: sorted(v) for g, v in out.items()}
+
+
+@pytest.mark.parametrize("seed", [3, 8])
+def test_spill_materialization_matches_materialize_rows(seed):
+    """Dense + spill materialization of each candidate (the dense store
+    at M=2 beside the spill table, through ``materialize_prefix`` and
+    ``spill_write``) yields, per graph, exactly the child embeddings
+    ``materialize_rows`` finds with an M that holds them all."""
+    graphs = random_db(6, n_vertices=8, extra_edge_prob=0.6, n_vlabels=2,
+                       n_elabels=1, seed=seed)
+    alphabet, _ = frequent_edges(graphs, 2)
+    triples = sorted({t for c in alphabet.canonical()
+                      for t in (c, (c[2], c[1], c[0]))})
+    eol = build_edge_ol(graphs, triples)
+    codes = [((0, 1, a, e, b),) for (a, e, b) in alphabet.canonical()]
+    full = level1_ol(codes, eol, max_embeddings=64)
+    ol, mask = np.asarray(full.ol), np.asarray(full.mask)
+    m, big, width = 2, 256, 3
+    dense_ol, dense_mask, table, key = _spill_layout(ol, mask, m)
+    nbr, code = (jnp.asarray(a[0]) for a in build_adjacency(
+        eol.src[None], eol.dst[None], eol.mask[None]))
+    dense_ol, dense_mask, table, key = map(
+        jnp.asarray, (dense_ol, dense_mask, table, key))
+    src, dst, em = map(jnp.asarray, (eol.src, eol.dst, eol.mask))
+    spilled_any = False
+    for cand in candidate_meta(generate_candidates(codes, alphabet), eol):
+        p, t = int(cand[0]), int(cand[4])
+        child, picked, _ = materialize_rows(
+            ol[p], mask[p], src[t], dst[t], em[t], jnp.asarray(cand),
+            max_embeddings=big, out_width=width)
+        gi, ri = np.nonzero(np.asarray(picked))
+        want = _by_graph(np.asarray(child)[gi, ri], gi)
+
+        cmeta = jnp.asarray(cand[None])
+        cdx, csx, bounds, spilled, n_rows, _ = spill_child_counts(
+            cmeta, 1, dense_ol, dense_mask, table, key, nbr, code,
+            n_triples=len(triples), max_embeddings=m)
+        d_ol, d_mask, _ = materialize_prefix(
+            cmeta, 1, dense_ol[None], dense_mask[None], src[None],
+            dst[None], em[None], n_slots=1, max_embeddings=m,
+            out_width=width)
+        d_mask = np.asarray(d_mask[0, 0]) & ~np.asarray(spilled[0])[:, None]
+        s_rows, s_key = spill_write(
+            cmeta, cdx, csx, bounds, spilled, dense_ol, table, key, nbr,
+            code, out_rows=bucket_size(max(int(n_rows), 1), 8),
+            out_width=width)
+        live = np.asarray(s_key) < NOKEY
+        assert int(live.sum()) == int(n_rows)
+        assert (np.diff(np.asarray(s_key)) >= 0).all()
+        gd, rd = np.nonzero(d_mask)
+        got = _by_graph(
+            np.concatenate([np.asarray(d_ol[0, 0])[gd, rd],
+                            np.asarray(s_rows)[live]]),
+            np.concatenate([gd, np.asarray(s_key)[live] % ol.shape[1]]))
+        assert got == want, cand
+        spilled_any |= int(n_rows) > 0
+    assert spilled_any

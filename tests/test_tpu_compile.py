@@ -155,3 +155,54 @@ def test_named_scopes_leave_the_v5e_level_program_unchanged(topo,
         assert f"mirage/{scope}/" in scoped, scope
     assert "mirage/" not in plain
     assert code_only(scoped) == code_only(plain)
+
+
+def test_spill_programs_compile_for_v5e(topo):
+    """The spill store's three programs (embedding_store="spill") at the
+    widths of a 4,110-molecule NCI1 fit at its widest level: 8
+    partitions of 514 graphs lane-aligned to 640, M=32, W=16 vertex
+    slots, 72 triples of up to 60 occurrences, 43 vertices of up to 12
+    neighbours, 4,096 candidates of up to 256 a parent, a 65,536-row
+    spill table a partition."""
+    from repro.core import mapreduce as mr
+    from repro.runtime import jax_compat
+
+    mesh = mr.MiningMesh(jax_compat.make_mesh((1,), ("w",),
+                                              devices=topo.devices[:1]))
+    rep = NamedSharding(mesh.mesh, mesh.replicated())
+    parts = NamedSharding(mesh.mesh, mesh.spec_parts())
+    g, m, w, t, f, v, d = 640, 32, 16, 72, 60, 43, 12
+    r, kc, cp, s, rows = 65536, 256, 4096, 32, 1024
+    store = [_shape(parts, (PP, P, g, m, w), jnp.int32),
+             _shape(parts, (PP, P, g, m), jnp.bool_),
+             _shape(parts, (PP, t, g, f), jnp.int32),
+             _shape(parts, (PP, t, g, f), jnp.int32),
+             _shape(parts, (PP, t, g, f), jnp.bool_)]
+    table = [_shape(parts, (PP, r, w), jnp.int32),
+             _shape(parts, (PP, r), jnp.int32),
+             _shape(parts, (PP, g, v, d), jnp.int32),
+             _shape(parts, (PP, g, v, d), jnp.int32)]
+    counts = [_shape(parts, (PP, s, g, m + 1), jnp.int32),
+              _shape(parts, (PP, r + 1, s), jnp.int32),
+              _shape(parts, (PP, P, g + 1), jnp.int32),
+              _shape(parts, (PP, s, g), jnp.bool_)]
+    cmeta = [_shape(rep, (s, 5), jnp.int32), _shape(rep, (), jnp.int32)]
+    programs = [
+        (mr._spill_support_program(mesh, "fused", True),
+         [_shape(rep, (cp, 5), jnp.int32), _shape(rep, (P, kc), jnp.int32),
+          _shape(rep, (cp,), jnp.int32), _shape(rep, (rows, 6), jnp.int32),
+          _shape(rep, (rows // 8, 2), jnp.int32),
+          _shape(rep, (cp,), jnp.int32), *store, *table]),
+        (mr._spill_count_program(mesh, m, t),
+         [*cmeta, *store[:2], *table]),
+        (mr._spill_write_program(mesh, m, w, r),
+         [*cmeta, *counts, *store, *table]),
+    ]
+    for i, (fn, specs) in enumerate(programs):
+        compiled = fn.lower(*specs).compile()
+        if i == 0:
+            assert "tpu_custom_call" in compiled.as_text()
+        mem = compiled.memory_analysis()
+        total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                 + mem.temp_size_in_bytes)
+        assert total < HBM_BYTES // 2, (i, mem)

@@ -352,3 +352,75 @@ def test_named_scopes_leave_the_compiled_program_unchanged(monkeypatch):
     plain = _compiled_text(key, specs)
     assert "mirage/" in scoped and "mirage/" not in plain
     assert code_only(scoped) == code_only(plain)
+
+
+# ---------------------------------------------------------------------------
+# the spill store's spans and scopes
+# ---------------------------------------------------------------------------
+
+def _adversarial():
+    return random_db(8, n_vertices=8, extra_edge_prob=0.9, n_vlabels=1,
+                     n_elabels=1, seed=7)
+
+
+@pytest.mark.parametrize("store", ["dense", "spill"])
+def test_level_and_fit_spans_count_the_spill_table(store):
+    miner = Mirage(MirageConfig(minsup=4, n_partitions=2, max_size=3,
+                                max_embeddings=2, embedding_store=store))
+    graphs = _adversarial()
+    miner.fit(graphs)
+    res, events = _traced(lambda: miner.fit(graphs))
+    levels = [e[3] for e in events if e[0] == "level"]
+    assert [(a["spill_rows"], a["spill_pairs"]) for a in levels] == [
+        (s.spill_rows, s.spill_pairs) for s in res.stats]
+    fit = next(e[3] for e in events if e[0] == "fit")
+    assert fit["spill_rows"] == sum(a["spill_rows"] for a in levels)
+    assert fit["spill_pairs"] == sum(a["spill_pairs"] for a in levels)
+    counted = [e[3] for e in events if e[0] == "spill_count"]
+    if store == "dense":
+        assert fit["spill_rows"] == fit["spill_pairs"] == 0
+        assert counted == []
+    else:
+        assert fit["spill_rows"] > fit["spill_pairs"] > 0
+        assert [a["rows"] for a in counted] == [
+            s.spill_rows for s in res.stats if s.n_frequent]
+
+
+_SPILL_PROGRAMS = ("_spill_support_program", "_spill_count_program",
+                   "_spill_write_program")
+
+
+def test_spill_ops_carry_their_own_scopes(monkeypatch):
+    """Every op of the spill join and of the spill materialization is
+    named by its own scope, and no other ``mirage/`` scope encloses it:
+    the outermost scope of an op's name is the one it is put down to."""
+    from repro.core import mapreduce
+
+    seen = []
+    for name in _SPILL_PROGRAMS:
+        orig = getattr(mapreduce, name)
+
+        def spying(*key, _orig=orig):
+            fn = _orig(*key)
+
+            def call(*args):
+                seen.append((_orig, key, [
+                    jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                         sharding=a.sharding)
+                    for a in args]))
+                return fn(*args)
+            return call
+
+        monkeypatch.setattr(mapreduce, name, spying)
+    Mirage(MirageConfig(minsup=4, n_partitions=2, max_size=3,
+                        max_embeddings=2,
+                        embedding_store="spill")).fit(_adversarial())
+    monkeypatch.undo()
+    names = set()
+    for prog, key, specs in {id(p): (p, k, s) for p, k, s in seen}.values():
+        text = prog.__wrapped__(*key).lower(*specs).compile().as_text()
+        for op in re.findall(r'op_name="([^"]*)"', text):
+            if "mirage/spill_" in op:
+                first = re.search(r"(?:^|/)mirage/([A-Za-z_]+)", op)
+                names.add(first.group(1))
+    assert names == {"spill_support", "spill_materialize"}
