@@ -36,8 +36,7 @@ import numpy as np
 
 from .candgen import Candidate
 from .dfscode import Code
-from .graphdb import Graph
-from .host_miner import OccurrenceList
+from .graphdb import FlatGraphs, Graph, row_ids
 
 __all__ = [
     "EdgeOL", "LevelOL", "CandidateMeta",
@@ -60,6 +59,8 @@ class EdgeOL:
     dst: np.ndarray        # (T, G, F) int32
     mask: np.ndarray       # (T, G, F) bool
     triple_index: dict[tuple[int, int, int], int]
+    occurrences: int       # directed occurrences placed in the lists
+    truncated: int         # those past ``max_occ`` in their (T, G) cell
 
     @property
     def shape(self):
@@ -70,8 +71,8 @@ class EdgeOL:
 class LevelOL:
     """Stacked OLs for all frequent patterns of one level."""
 
-    ol: jnp.ndarray        # (P, G, M, K) int32, PAD-filled
-    mask: jnp.ndarray      # (P, G, M) bool
+    ol: np.ndarray | jax.Array     # (P, G, M, K) int32, PAD-filled
+    mask: np.ndarray | jax.Array   # (P, G, M) bool
 
     @property
     def P(self):
@@ -90,33 +91,56 @@ def build_edge_ol(
     ``triples`` must be the *directed* closure of the frequent-edge
     alphabet so every partition indexes the same table (the shared key
     space that replaces Hadoop's shuffle-by-string-key).
+
+    Each edge (u, v) occurs twice, u -> v then v -> u.  A (triple,
+    graph) cell lists its occurrences in edge order, the first
+    ``max_occ`` of them when that is given.  The occurrences of all
+    graphs are sorted into their cells at once and scattered.
     """
     tindex = {tuple(t): i for i, t in enumerate(triples)}
     G = pad_graphs or len(graphs)
-    occs: list[list[list[tuple[int, int]]]] = [
-        [[] for _ in range(G)] for _ in range(len(triples))]
-    for gi, g in enumerate(graphs):
-        for (u, v), el in zip(g.edges, g.elabels):
-            lu, lv = int(g.vlabels[u]), int(g.vlabels[v])
-            for (a, la, b, lb) in ((int(u), lu, int(v), lv),
-                                   (int(v), lv, int(u), lu)):
-                ti = tindex.get((la, int(el), lb))
-                if ti is not None:
-                    occs[ti][gi].append((a, b))
-    F = max_occ or max((len(o) for row in occs for o in row), default=1)
-    F = max(F, 1)
+    if len(graphs) > G:
+        raise ValueError(f"pad_graphs={G} < {len(graphs)} graphs")
     T = len(triples)
-    src = np.full((T, G, F), PAD, np.int32)
-    dst = np.full((T, G, F), PAD, np.int32)
+    flat = FlatGraphs.of(graphs)
+    lu, lv = flat.endpoint_labels()
+    # every directed occurrence, in (graph, edge, orientation) order
+    src = flat.edges.reshape(-1)
+    dst = flat.edges[:, ::-1].reshape(-1)
+    la = np.stack([lu, lv], axis=1).reshape(-1)
+    lb = np.stack([lv, lu], axis=1).reshape(-1)
+    el = np.repeat(flat.elabels, 2)
+    graph = np.repeat(flat.graph, 2)
+    # each occurrence's index in the table, -1 off it; a triple listed
+    # twice keeps its last index, as ``tindex`` does
+    table = np.asarray(triples, np.int64).reshape(-1, 3)
+    ids, n_ids = row_ids(*(np.concatenate([table[:, k], col])
+                           for k, col in enumerate((la, el, lb))))
+    last = np.fromiter(tindex.values(), np.int64, len(tindex))
+    lookup = np.full(n_ids, -1, np.int64)
+    lookup[ids[last]] = last
+    ti = lookup[ids[T:]]
+    on = ti >= 0
+    cell = ti[on] * G + graph[on]
+    order = np.argsort(cell, kind="stable")
+    cell, src, dst = cell[order], src[on][order], dst[on][order]
+    # an occurrence's slot: its rank within its cell
+    idx = np.arange(cell.shape[0])
+    new = np.r_[True, cell[1:] != cell[:-1]] if cell.size else idx > 0
+    slot = idx - np.maximum.accumulate(np.where(new, idx, 0))
+    F = max(max_occ or (int(slot.max()) + 1 if slot.size else 1), 1)
+    fits = slot < F
+    cell, slot = cell[fits], slot[fits]
+    out_src = np.full((T, G, F), PAD, np.int32)
+    out_dst = np.full((T, G, F), PAD, np.int32)
     mask = np.zeros((T, G, F), bool)
-    for ti in range(T):
-        for gi in range(G):
-            o = occs[ti][gi][:F]
-            if o:
-                src[ti, gi, : len(o)] = [p[0] for p in o]
-                dst[ti, gi, : len(o)] = [p[1] for p in o]
-                mask[ti, gi, : len(o)] = True
-    return EdgeOL(np.asarray(triples, np.int32), src, dst, mask, tindex)
+    t, g = np.divmod(cell, G)
+    out_src[t, g, slot] = src[fits]
+    out_dst[t, g, slot] = dst[fits]
+    mask[t, g, slot] = True
+    return EdgeOL(np.asarray(triples, np.int32), out_src, out_dst, mask,
+                  tindex, occurrences=int(fits.sum()),
+                  truncated=int(fits.size - fits.sum()))
 
 
 def level1_ol(
@@ -125,7 +149,8 @@ def level1_ol(
     *,
     max_embeddings: int,
 ) -> LevelOL:
-    """F_1 OLs from the edge-OL (preparation phase's emitted patterns).
+    """F_1 OLs from the edge-OL (preparation phase's emitted patterns),
+    as host arrays: the one upload of the store comes after.
 
     A single-edge pattern (0,1,a,e,b) embeds at every directed occurrence
     of (a,e,b); when a == b the two orientations are distinct embeddings
@@ -133,16 +158,15 @@ def level1_ol(
     """
     P, M = len(codes), max_embeddings
     _, G, F = eol.src.shape
+    ti = np.array([eol.triple_index[code[0][2:]] for code in codes],
+                  np.int64)
+    take = min(M, F)
     ol = np.full((P, G, M, 2), PAD, np.int32)
     mask = np.zeros((P, G, M), bool)
-    for pi, code in enumerate(codes):
-        (i, j, a, e, b) = code[0]
-        ti = eol.triple_index[(a, e, b)]
-        take = min(M, F)
-        ol[pi, :, :take, 0] = eol.src[ti, :, :take]
-        ol[pi, :, :take, 1] = eol.dst[ti, :, :take]
-        mask[pi, :, :take] = eol.mask[ti, :, :take]
-    return LevelOL(jnp.asarray(ol), jnp.asarray(mask))
+    ol[:, :, :take, 0] = eol.src[ti, :, :take]
+    ol[:, :, :take, 1] = eol.dst[ti, :, :take]
+    mask[:, :, :take] = eol.mask[ti, :, :take]
+    return LevelOL(ol, mask)
 
 
 def candidate_meta(cands: Sequence[Candidate], eol: EdgeOL) -> np.ndarray:
@@ -391,14 +415,18 @@ def build_adjacency(src: np.ndarray, dst: np.ndarray, emask: np.ndarray
     occurrence) order.  The spill join reads a backward edge as THE code
     of the occurrence between two vertices, so a graph may hold at most
     one occurrence per ordered vertex pair: a ``ValueError`` otherwise."""
-    n, t, g, f = np.nonzero(emask)
-    u = src[n, t, g, f].astype(np.int64)
-    v = dst[n, t, g, f].astype(np.int64)
+    at = np.flatnonzero(emask)
+    u = src.reshape(-1)[at].astype(np.int64)
+    v = dst.reshape(-1)[at].astype(np.int64)
+    n, t, g, _ = np.unravel_index(at, src.shape)
     NP, _, G, _ = src.shape
     V = int(max(u.max(), v.max())) + 1 if u.size else 1
-    order = np.lexsort((f, t, u, g, n))
-    n, t, g, u, v = n[order], t[order], g[order], u[order], v[order]
+    # the occurrences come in (n, t, g, f) order, so a stable sort by
+    # (n, g, u) keeps each source vertex's entries in (t, f) order
     group = (n * G + g) * V + u
+    order = np.argsort(group, kind="stable")
+    group, n, t, g, u, v = (group[order], n[order], t[order], g[order],
+                            u[order], v[order])
     pair = group * V + v
     if np.unique(pair).size != pair.size:
         raise ValueError("the spill store needs graphs with at most one "

@@ -26,6 +26,7 @@ from typing import Iterable, Sequence
 import numpy as np
 
 __all__ = [
+    "FlatGraphs",
     "Graph",
     "GraphDB",
     "GraphValidationError",
@@ -34,6 +35,7 @@ __all__ = [
     "decode_db",
     "random_db",
     "pubchem_like_db",
+    "row_ids",
 ]
 
 
@@ -133,6 +135,87 @@ class GraphDB:
         }
 
 
+@dataclasses.dataclass
+class FlatGraphs:
+    """A list of graphs as one set of flat arrays: every graph's vertex
+    labels, then every graph's edges, in list then edge order.  Edge
+    endpoints stay each graph's own vertex ids.  Host preparation works
+    on these with array ops instead of a Python loop per edge."""
+
+    n_v: np.ndarray        # (n,) int64 vertices per graph
+    n_e: np.ndarray        # (n,) int64 edges per graph
+    vlabels: np.ndarray    # (sum n_v,) vertex labels
+    edges: np.ndarray      # (sum n_e, 2) endpoints, per-graph ids
+    elabels: np.ndarray    # (sum n_e,) edge labels
+    graph: np.ndarray      # (sum n_e,) int64 the list index of each edge
+
+    @classmethod
+    def of(cls, graphs: Sequence[Graph]) -> "FlatGraphs":
+        n_v = np.array([g.vlabels.shape[0] for g in graphs], np.int64)
+        n_e = np.array([g.edges.shape[0] for g in graphs], np.int64)
+        return cls(
+            n_v, n_e,
+            np.concatenate([g.vlabels for g in graphs]
+                           or [np.empty(0, np.int32)]),
+            np.concatenate([g.edges for g in graphs]
+                           or [np.empty((0, 2), np.int32)]),
+            np.concatenate([g.elabels for g in graphs]
+                           or [np.empty(0, np.int32)]),
+            np.repeat(np.arange(len(graphs), dtype=np.int64), n_e))
+
+    def vertex_offset(self) -> np.ndarray:
+        """(n,) index in ``vlabels`` of each graph's vertex 0."""
+        return np.cumsum(self.n_v) - self.n_v
+
+    def endpoint_labels(self) -> tuple[np.ndarray, np.ndarray]:
+        """The vertex labels of each edge's two endpoints."""
+        at = self.vertex_offset()[self.graph][:, None] + self.edges
+        return self.vlabels[at[:, 0]], self.vlabels[at[:, 1]]
+
+    def keep_edges(self, keep: np.ndarray) -> "FlatGraphs":
+        """``Graph.keep_edges`` on every graph at once: the edges where
+        ``keep`` holds, without the vertices they no longer touch, and
+        each graph's vertex ids dense again in their old order."""
+        edges, graph = self.edges[keep], self.graph[keep]
+        vertex = self.vertex_offset()[graph][:, None] + edges
+        used = np.zeros(self.vlabels.shape[0], bool)
+        used[vertex.reshape(-1)] = True
+        n = self.n_v.shape[0]
+        n_v = np.bincount(np.repeat(np.arange(n), self.n_v)[used],
+                          minlength=n).astype(np.int64)
+        # a vertex's new id: the used vertices before it in its graph
+        before = np.cumsum(used) - used
+        base = (np.cumsum(n_v) - n_v)[graph][:, None]
+        return FlatGraphs(
+            n_v, np.bincount(graph, minlength=n).astype(np.int64),
+            self.vlabels[used],
+            (before[vertex] - base).astype(self.edges.dtype),
+            self.elabels[keep], graph)
+
+    def graphs(self) -> list[Graph]:
+        """One :class:`Graph` per graph of the list."""
+        if not self.n_v.shape[0]:
+            return []
+        vl = np.split(self.vlabels, np.cumsum(self.n_v)[:-1])
+        at = np.cumsum(self.n_e)[:-1]
+        return [Graph(*g) for g in zip(vl, np.split(self.edges, at),
+                                       np.split(self.elabels, at))]
+
+
+def row_ids(*cols: np.ndarray) -> tuple[np.ndarray, int]:
+    """Dense ids of the rows ``(cols[0][i], cols[1][i], ...)`` of equal
+    length integer columns, in the rows' lexicographic order, and the
+    number of distinct rows.  Each column is ranked and the running key
+    re-ranked, so no key outgrows int64 whatever the values."""
+    ids, n = np.zeros(cols[0].shape[0], np.int64), 1
+    for c in cols:
+        vals, rank = np.unique(c, return_inverse=True)
+        keys, ids = np.unique(ids * len(vals) + rank.reshape(-1),
+                              return_inverse=True)
+        n = len(keys)
+    return ids.reshape(-1), n
+
+
 def validate_db(graphs: Sequence[Graph]) -> None:
     """Validate a user-supplied transaction database at the load
     boundary (``make_partitions`` calls this before any filtering).
@@ -145,9 +228,42 @@ def validate_db(graphs: Sequence[Graph]) -> None:
     *user input* is checked — internally derived graphs (e.g. after
     infrequent-edge filtering, which legitimately empties graphs) never
     pass through here.
+
+    Every check runs once over the whole DB's arrays; only a DB that
+    fails one goes through the per-graph checks, which name the first
+    graph and edge at fault.
     """
     if len(graphs) == 0:
         raise GraphValidationError("empty database: no graphs to mine")
+    if not _passes_every_check(graphs):
+        _validate_each(graphs)
+
+
+def _passes_every_check(graphs: Sequence[Graph]) -> bool:
+    """True when no graph fails a check of ``validate_db``, tested over
+    the concatenated arrays.  False also for arrays of a shape the
+    concatenation cannot take; ``_validate_each`` judges those."""
+    for g in graphs:
+        if not (isinstance(g, Graph) and g.vlabels.ndim == 1
+                and g.vlabels.shape[0] > 0 and g.edges.ndim == 2
+                and g.edges.shape[1] == 2
+                and g.elabels.shape == g.edges.shape[:1]):
+            return False
+    flat = FlatGraphs.of(graphs)
+    e = flat.edges
+    if (flat.vlabels.min(initial=0) < 0 or flat.elabels.min(initial=0) < 0
+            or (e < 0).any() or (e >= flat.n_v[flat.graph][:, None]).any()
+            or (e[:, 0] == e[:, 1]).any()):
+        return False
+    # exact row duplicates within a graph, as ``np.unique(axis=0)`` sees
+    order = np.lexsort((e[:, 1], e[:, 0], flat.graph))
+    row = np.stack([flat.graph, e[:, 0], e[:, 1]], axis=1)[order]
+    return not (row[1:] == row[:-1]).all(axis=1).any()
+
+
+def _validate_each(graphs: Sequence[Graph]) -> None:
+    """``validate_db``'s checks graph by graph: raises at the first
+    defect of the first graph that has one."""
     for i, g in enumerate(graphs):
         if not isinstance(g, Graph):
             raise GraphValidationError(

@@ -551,14 +551,15 @@ class Mirage:
             eols = [build_edge_ol(p, triples, pad_graphs=G,
                                   max_occ=cfg.max_occ)
                     for p in part.partitions]
-            F = max(e.src.shape[-1] for e in eols)
-            src = np.stack([_pad_f(e.src, F, -1) for e in eols])   # (NP,T,G,F)
-            dst = np.stack([_pad_f(e.dst, F, -1) for e in eols])
-            emask = np.stack([_pad_f(e.mask, F, False) for e in eols])
+            src = _stack_padded([e.src for e in eols], -1)   # (NP,T,G,F)
+            dst = _stack_padded([e.dst for e in eols], -1)
+            emask = _stack_padded([e.mask for e in eols], False)
+            F = src.shape[-1]
             # the spill join's view of the same occurrences
             adjacency = (build_adjacency(src, dst, emask) if spilling
                          else ())
-            sp.set(F=F)
+            sp.set(F=F, occurrences=sum(e.occurrences for e in eols),
+                   truncated=sum(e.truncated for e in eols))
         eol0 = eols[0]   # triple_index identical across partitions
 
         codes = [((0, 1, a, e, b),) for (a, e, b) in alphabet.canonical()]
@@ -570,20 +571,21 @@ class Mirage:
             M1 = bk.embeddings(M1, cfg.max_embeddings)
         with tracing.Span("level1", codes=len(codes)):
             lvl1 = [level1_ol(codes, e, max_embeddings=M1) for e in eols]
-            pol = np.stack([np.asarray(l.ol) for l in lvl1])   # (NP,P,G,M,2)
-            pmask = np.stack([np.asarray(l.mask) for l in lvl1])
+            P1, K1 = len(codes), 2
             if bk is not None:
                 # bucket the level-1 store into the same (P, K) family
                 # the child stores live in, so the level-2 program is
                 # often THE program every later level reuses
-                pol, pmask = _pad_store(
-                    pol, pmask, p_to=bucket_size(len(codes), bk.s_floor),
-                    k_to=bk.vertex_slots(2))
+                P1, K1 = bucket_size(P1, bk.s_floor), bk.vertex_slots(2)
+            pol = _stack_padded([l.ol for l in lvl1], -1,
+                                (P1, G, M1, K1))             # (NP,P,G,M,K)
+            pmask = _stack_padded([l.mask for l in lvl1], False,
+                                  (P1, G, M1))
 
-            supports: dict[Code, int] = {}
-            for c in codes:
-                ti = eol0.triple_index[c[0][2:]]
-                supports[c] = int(emask[:, ti].any(axis=-1).sum())
+            # graphs holding each triple, over all partitions
+            held = emask.any(axis=-1).sum(axis=(0, 2))
+            supports: dict[Code, int] = {
+                c: int(held[eol0.triple_index[c[0][2:]]]) for c in codes}
         levels: list[list[Code]] = [list(codes)]
         stats: list[LevelStats] = []
         total_overflow = 0
@@ -1623,12 +1625,17 @@ def _by_parent(meta: np.ndarray, n_parents: int, Cp: int
     return by_parent, cpos
 
 
-def _pad_f(a: np.ndarray, F: int, fill) -> np.ndarray:
-    pad = F - a.shape[-1]
-    if pad == 0:
-        return a
-    widths = [(0, 0)] * (a.ndim - 1) + [(0, pad)]
-    return np.pad(a, widths, constant_values=fill)
+def _stack_padded(arrays: list[np.ndarray], fill,
+                  shape: Optional[tuple[int, ...]] = None) -> np.ndarray:
+    """Stack per-partition arrays into one ``(NP, *shape)`` array, each
+    padded with ``fill`` at the end of every axis: one allocation and
+    one copy.  ``shape`` defaults to the largest size on each axis."""
+    if shape is None:
+        shape = tuple(map(max, zip(*(a.shape for a in arrays))))
+    out = np.full((len(arrays), *shape), fill, arrays[0].dtype)
+    for o, a in zip(out, arrays):
+        o[tuple(slice(n) for n in a.shape)] = a
+    return out
 
 
 def _imbalance(cost: np.ndarray, w: int) -> float:

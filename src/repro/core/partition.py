@@ -20,12 +20,12 @@ Three schemes:
 from __future__ import annotations
 
 import dataclasses
+import heapq
 from typing import Sequence
 
 import numpy as np
 
-from .graphdb import Graph, validate_db
-from .host_miner import frequent_edges
+from .graphdb import FlatGraphs, Graph, row_ids, validate_db
 from .candgen import EdgeAlphabet
 
 __all__ = ["PartitionResult", "filter_infrequent_edges", "graph_density",
@@ -44,16 +44,26 @@ class PartitionResult:
 def filter_infrequent_edges(
     graphs: Sequence[Graph], minsup: int
 ) -> tuple[list[Graph], EdgeAlphabet]:
-    """Drop every edge whose label triple is globally infrequent."""
-    alphabet, _ = frequent_edges(graphs, minsup)
-    out = []
-    for g in graphs:
-        keep = np.zeros(g.n_edges, bool)
-        for k, ((u, v), el) in enumerate(zip(g.edges, g.elabels)):
-            t = (int(g.vlabels[u]), int(el), int(g.vlabels[v]))
-            keep[k] = t in alphabet
-        out.append(g.keep_edges(keep))
-    return out, alphabet
+    """Drop every edge whose label triple is globally infrequent.
+
+    A triple's support is the number of graphs holding an edge of it,
+    counted over the distinct (canonical triple, graph) pairs of the
+    whole DB's edges at once."""
+    flat = FlatGraphs.of(graphs)
+    lu, lv = flat.endpoint_labels()
+    a, e, b = np.minimum(lu, lv), flat.elabels, np.maximum(lu, lv)
+    triple, n_triples = row_ids(a, e, b)
+    pairs = np.unique(triple * max(len(graphs), 1) + flat.graph)
+    support = np.bincount(pairs // max(len(graphs), 1),
+                          minlength=n_triples)
+    frequent = support >= minsup
+    # one edge of each triple, to read the triple's labels from
+    first = np.zeros(n_triples, np.int64)
+    first[triple] = np.arange(triple.shape[0])
+    first = first[frequent]
+    alphabet = EdgeAlphabet(zip(a[first].tolist(), e[first].tolist(),
+                                b[first].tolist()))
+    return flat.keep_edges(frequent[triple]).graphs(), alphabet
 
 
 def graph_density(g: Graph) -> float:
@@ -95,34 +105,33 @@ def make_partitions(
                   else int(minsup))
     filtered, alphabet = filter_infrequent_edges(graphs, abs_minsup)
 
-    ids = list(range(n))
-    parts: list[list[int]] = [[] for _ in range(n_partitions)]
+    n_e = np.array([g.n_edges for g in filtered], np.int64)
     if scheme == 1:
-        for i in ids:
-            parts[i % n_partitions].append(i)
+        parts = [list(range(p, n, n_partitions))
+                 for p in range(n_partitions)]
     elif scheme == 2:
-        load = np.zeros(n_partitions, np.int64)
+        parts = [[] for _ in range(n_partitions)]
         # LPT: heaviest graphs first onto the lightest partition;
         # ties (e.g. fully-filtered zero-edge graphs) break on graph
-        # count so no partition is starved empty
-        order = sorted(ids, key=lambda i: -filtered[i].n_edges)
-        for i in order:
-            p = min(range(n_partitions),
-                    key=lambda b: (load[b], len(parts[b])))
+        # count, then on the lower partition, so no partition is
+        # starved empty
+        lightest = [(0, 0, p) for p in range(n_partitions)]
+        for i in np.argsort(-n_e, kind="stable").tolist():
+            load, count, p = lightest[0]
             parts[p].append(i)
-            load[p] += filtered[i].n_edges
+            heapq.heapreplace(lightest, (load + int(n_e[i]), count + 1, p))
     elif scheme == "density":
         # densest graphs first, snake-dealt (0..NP-1, NP-1..0, ...): each
         # pass hands every partition exactly one graph of comparable
         # density, and the direction flip cancels the within-pass bias —
         # graph counts stay balanced (|Δ| <= 1) by construction, so no
-        # partition starves even when the DB is density-uniform
-        order = sorted(ids, key=lambda i: (-graph_density(filtered[i]),
-                                           -filtered[i].n_edges))
-        for rank, i in enumerate(order):
-            sweep, pos = divmod(rank, n_partitions)
-            parts[pos if sweep % 2 == 0 else
-                  n_partitions - 1 - pos].append(i)
+        # partition starves even when the DB is density-uniform; edge
+        # count breaks ties
+        density = np.array([graph_density(g) for g in filtered])
+        order = np.lexsort((-n_e, -density))
+        sweep, pos = np.divmod(np.arange(n), n_partitions)
+        deal = np.where(sweep % 2 == 0, pos, n_partitions - 1 - pos)
+        parts = [order[deal == p].tolist() for p in range(n_partitions)]
     else:
         raise ValueError(f"unknown scheme {scheme!r} (1 | 2 | 'density')")
 

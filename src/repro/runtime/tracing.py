@@ -16,7 +16,9 @@ The phases (args in brackets; "levels"/"compiles"/... at close):
                     compiles, wire_fetches, gc_gen2, gc_s, canon_tested,
                     canon_early, spill_rows, spill_pairs]
   partition         ``make_partitions`` [n_parts]
-  edge_ol_build     the per-partition edge OLs, padded and stacked [F]
+  edge_ol_build     the per-partition edge OLs, padded and stacked [F,
+                    occurrences, truncated: the directed occurrences
+                    placed, and those past ``max_occ``]
   level1            the level-1 OLs and supports [codes]
   upload            the stores' host-to-device copy [bytes]
   level             one level of the mining loop [level, candidates, Cp,

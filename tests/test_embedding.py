@@ -10,8 +10,10 @@ from repro.core.embedding import (NOKEY, build_adjacency, build_edge_ol,
                                   local_supports_ref, materialize_prefix,
                                   materialize_rows, spill_child_counts,
                                   spill_write, LevelOL)
-from repro.core.graphdb import paper_toy_db, random_db
+from repro.core.graphdb import Graph, paper_toy_db, pubchem_like_db, random_db
 from repro.core.host_miner import frequent_edges, mine_host
+
+import prep_reference
 
 
 def dense_mine_levels(graphs, minsup, max_size, max_embeddings=64, max_occ=None):
@@ -190,3 +192,84 @@ def test_spill_materialization_matches_materialize_rows(seed):
         assert got == want, cand
         spilled_any |= int(n_rows) > 0
     assert spilled_any
+
+
+# ---------------------------------------------------------------------------
+# host preparation on flat arrays == the frozen loop builders
+# ---------------------------------------------------------------------------
+
+def _dense_one_label():
+    # one vertex and one edge label: every triple is (0, 0, 0), a == b
+    return random_db(8, n_vertices=8, extra_edge_prob=0.9, n_vlabels=1,
+                     n_elabels=1, seed=7)
+
+
+_PREP_CASES = {
+    # name: (graphs, minsup, extra padded graphs, max_occ, M, table order)
+    "three_edge_labels": (random_db(10, n_vertices=7, extra_edge_prob=0.5,
+                                    n_vlabels=3, n_elabels=3, seed=21),
+                          3, 0, None, 64, "sorted"),
+    "one_label_a_eq_b": (_dense_one_label(), 2, 5, None, 64, "sorted"),
+    "truncated_at_max_occ": (_dense_one_label(), 2, 3, 3, 64, "sorted"),
+    "level1_narrower_than_F": (_dense_one_label(), 2, 0, None, 2,
+                               "sorted"),
+    "paper_toy": (paper_toy_db(), 2, 2, None, 8, "sorted"),
+    "molecules_reversed_table": (pubchem_like_db(12, seed=5, avg_edges=12),
+                                 3, 4, 4, 16, "reversed"),
+    "infrequent_edges_off_table": (random_db(9, n_vertices=6,
+                                             extra_edge_prob=0.3,
+                                             n_vlabels=4, n_elabels=2,
+                                             seed=13), 4, 1, None, 8,
+                                   "sorted"),
+    "edgeless_and_isolated": ([Graph([0], np.empty((0, 2)), []),
+                               Graph([0, 1, 1], [(0, 1)], [0]),
+                               Graph([1, 0, 1], [(0, 1), (1, 2)], [0, 0])],
+                              1, 1, None, 4, "sorted"),
+}
+
+
+@pytest.mark.parametrize("case", list(_PREP_CASES))
+def test_prep_arrays_equal_the_loop_builders(case):
+    """``build_edge_ol``, ``build_adjacency`` and ``level1_ol`` return
+    exactly what the loop builders return: same dtypes, shapes, values
+    and triple index, the raw DB's infrequent occurrences left off."""
+    graphs, minsup, extra, max_occ, M, order = _PREP_CASES[case]
+    alphabet, _ = frequent_edges(graphs, minsup)
+    triples = sorted({t for c in alphabet.canonical()
+                      for t in (c, (c[2], c[1], c[0]))})
+    if order == "reversed":
+        triples = triples[::-1]
+    G = len(graphs) + extra
+    eol = build_edge_ol(graphs, triples, pad_graphs=G, max_occ=max_occ)
+    src, dst, mask, tindex = prep_reference.build_edge_ol(
+        graphs, triples, pad_graphs=G, max_occ=max_occ)
+    for got, want in ((eol.src, src), (eol.dst, dst), (eol.mask, mask)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(got, want)
+    assert eol.triple_index == tindex
+    np.testing.assert_array_equal(eol.triples, np.asarray(triples, np.int32))
+    whole = prep_reference.build_edge_ol(graphs, triples, pad_graphs=G)[2]
+    assert eol.occurrences == int(mask.sum())
+    assert eol.truncated == int(whole.sum() - mask.sum())
+    assert (eol.truncated > 0) == (max_occ is not None)
+
+    for got, want in zip(
+            build_adjacency(eol.src[None], eol.dst[None], eol.mask[None]),
+            build_adjacency(src[None], dst[None], mask[None])):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got, want)
+
+    codes = [((0, 1, a, e, b),) for (a, e, b) in alphabet.canonical()]
+    level = level1_ol(codes, eol, max_embeddings=M)
+    assert isinstance(level.ol, np.ndarray)
+    assert isinstance(level.mask, np.ndarray)
+    ref_ol, ref_mask = prep_reference.level1_ol(codes, src, dst, mask,
+                                                tindex, max_embeddings=M)
+    for got, want in ((level.ol, ref_ol), (level.mask, ref_mask)):
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(got, want)
+
+
+def test_build_edge_ol_rejects_a_pad_below_the_graphs():
+    with pytest.raises(ValueError, match="pad_graphs"):
+        build_edge_ol(paper_toy_db(), [(0, 0, 1)], pad_graphs=2)

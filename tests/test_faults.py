@@ -233,10 +233,31 @@ def test_validate_db_accepts_real_databases():
     (_g([0, 1], [(0, 1)], [0, 0]), "edge labels"),
     (_g([0, 1], [(0, 0)], [0]), "self-loop"),
     (_g([0, 1, 2], [(0, 1), (1, 0)], [0, 0]), "duplicate"),
+    # whole DBs: the defect in a later graph, or two bad graphs of which
+    # the first is named, with the edge at fault
+    ([*paper_toy_db(), *paper_toy_db(),
+      _g([0, 1, 2], [(0, 1), (1, 2), (0, 3)], [0, 0, 0])],
+     r"^graph 6, edge 2: dangling edge endpoint 3 outside \[0, 3\)$"),
+    ([*paper_toy_db(), _g([0, 1, 2], [(1, 2), (0, 1), (1, 2)], [0, 0, 0]),
+      _g([0, 1], [(0, 5)], [0])],
+     r"^graph 3, edge 0: duplicate edge \(1, 2\)"),
+    ([*paper_toy_db(), paper_toy_db()[1],
+      _g([0, 1, 2], [(0, 1), (2, 2)], [0, 0]), _g([0, -4], [], [])],
+     r"^graph 4, edge 1: self-loop at vertex 2$"),
+    ([*paper_toy_db(), paper_toy_db()[2],
+      _g([0, 1, 2], [(0, 1), (1, 2), (0, 2)], [0, 1, -3]),
+      _g([0, 1], [(0, 1)], [-1])],
+     r"^graph 4, edge 2: negative edge label -3$"),
+    ([*paper_toy_db(), _g([0, -2], [(0, 1)], [0]), _g([], [], [])],
+     r"^graph 3: negative vertex label -2$"),
+    ([paper_toy_db()[0], _g([0, 1], [(0, 1)], [0, 0]),
+      _g([0, 1], [(0, 0)], [0])],
+     r"^graph 1: 1 edges but 2 edge labels$"),
 ])
 def test_validate_db_rejects_malformed_graphs(bad, msg):
+    db = bad if isinstance(bad, list) else [paper_toy_db()[0], bad]
     with pytest.raises(GraphValidationError, match=msg):
-        validate_db([paper_toy_db()[0], bad])
+        validate_db(db)
 
 
 def test_validate_db_rejects_empty_database():

@@ -12,6 +12,8 @@ from repro.core.host_miner import mine_host
 from repro.core.mining import Mirage, MirageConfig
 from repro.core.naive import mine_naive
 
+import prep_reference
+
 
 def assert_same_result(dist, ref):
     assert [set(l) for l in dist.levels] == [set(l) for l in ref.levels]
@@ -194,3 +196,60 @@ def test_multidevice_mining_subprocess():
                          timeout=900)
     assert out.returncode == 0, out.stderr[-4000:]
     assert "MULTIDEV-OK" in out.stdout
+
+
+def _emptied_by_filtering():
+    # the first six graphs hold only singleton triples, which no minsup
+    # of 2 or more keeps; the isolated vertex of the last goes with its
+    # graph's edges either way
+    from repro.core.graphdb import Graph
+    return ([Graph([i, i + 1], [(0, 1)], [i]) for i in range(6)]
+            + random_db(8, n_vertices=6, extra_edge_prob=0.4, n_vlabels=2,
+                        n_elabels=2, seed=17)
+            + [Graph([1, 0, 1], [(0, 2)], [0])])
+
+
+_PARTITION_DBS = {
+    "three_labels_int_minsup": (random_db(15, n_vertices=7,
+                                          extra_edge_prob=0.5, n_vlabels=3,
+                                          n_elabels=3, seed=31), 3, 4),
+    "three_labels_float_minsup": (random_db(15, n_vertices=7,
+                                            extra_edge_prob=0.5,
+                                            n_vlabels=3, n_elabels=3,
+                                            seed=31), 0.3, 4),
+    "emptied_by_filtering": (_emptied_by_filtering(), 2, 3),
+    "molecules": (pubchem_like_db(20, seed=2, avg_edges=10), 0.25, 5),
+    "one_partition": (paper_toy_db(), 2, 1),
+}
+
+
+@pytest.mark.parametrize("scheme", [1, 2, "density"])
+@pytest.mark.parametrize("db", list(_PARTITION_DBS))
+def test_make_partitions_equals_the_loop_reference(db, scheme):
+    """Graph ids (LPT and density tie-breaks included), alphabet,
+    threshold and every filtered graph, with its re-densified vertex
+    ids, equal those of the loop implementation."""
+    from repro.core.partition import filter_infrequent_edges, make_partitions
+    graphs, minsup, n_parts = _PARTITION_DBS[db]
+    got = make_partitions(graphs, minsup, n_parts, scheme=scheme)
+    want = prep_reference.make_partitions(graphs, minsup, n_parts,
+                                          scheme=scheme)
+    assert got.graph_ids == want.graph_ids
+    assert all(type(i) is int for ids in got.graph_ids for i in ids)
+    assert got.alphabet._set == want.alphabet._set
+    assert got.alphabet.canonical() == want.alphabet.canonical()
+    assert (got.alphabet.vlabels, got.alphabet.elabels) == (
+        want.alphabet.vlabels, want.alphabet.elabels)
+    assert (got.minsup, got.n_graphs) == (want.minsup, want.n_graphs)
+    filtered, alphabet = filter_infrequent_edges(graphs, got.minsup)
+    ref_filtered, _ = prep_reference.filter_infrequent_edges(graphs,
+                                                             got.minsup)
+    assert alphabet._set == want.alphabet._set
+    pairs = [(g, w) for gp, wp in zip(got.partitions, want.partitions)
+             for g, w in zip(gp, wp)] + list(zip(filtered, ref_filtered))
+    assert len(pairs) == 2 * len(graphs)
+    for g, w in pairs:
+        for name in ("vlabels", "edges", "elabels"):
+            a, b = getattr(g, name), getattr(w, name)
+            assert a.dtype == b.dtype and a.shape == b.shape, name
+            np.testing.assert_array_equal(a, b)

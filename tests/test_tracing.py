@@ -116,6 +116,33 @@ def test_every_phase_is_a_span_nested_where_it_runs(warm_fit):
     assert any(_inside(e, levels[0]) for e in events if e[0] == "gc")
 
 
+def _directed_occurrences(graphs, minsup, n_parts):
+    """Both orientations of every edge left after the infrequent-edge
+    filter: the occurrences an uncut edge OL places."""
+    from repro.core.partition import make_partitions
+    part = make_partitions(graphs, minsup, n_parts)
+    return 2 * sum(g.n_edges for p in part.partitions for g in p)
+
+
+def test_edge_ol_build_counts_placed_and_truncated_occurrences(warm_fit):
+    _, events = warm_fit
+    (args,) = [e[3] for e in events if e[0] == "edge_ol_build"]
+    assert args["truncated"] == 0
+    assert args["occurrences"] == _directed_occurrences(_db(), 4, 2)
+
+    # an edge OL narrower than the widest list drops the rest of it
+    graphs = _adversarial()
+    whole = _directed_occurrences(graphs, 4, 2)
+    miner = Mirage(MirageConfig(minsup=4, n_partitions=2, max_size=2,
+                                max_occ=2))
+    miner.fit(graphs)
+    _, events = _traced(lambda: miner.fit(graphs))
+    (args,) = [e[3] for e in events if e[0] == "edge_ol_build"]
+    assert args["F"] == 2
+    assert args["truncated"] > 0
+    assert args["occurrences"] + args["truncated"] == whole
+
+
 def test_wait_and_speculation_nest_in_their_own_level(warm_fit):
     _, events = warm_fit
     levels = [e for e in events if e[0] == "level"]
